@@ -69,7 +69,7 @@ def _prime(index) -> None:
     index._accounted_stores = index._write_account()
     try:
         index.snapshot()
-    except (NotImplementedError, ImportError):
+    except NotImplementedError:
         pass
 
 

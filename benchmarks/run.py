@@ -61,6 +61,8 @@ def main() -> None:
     ap.add_argument("--streams", type=int, default=4,
                     help="client streams driving the sharded sweep")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.configure()
     if args.trace:
         from repro import obs
         obs.reset()

@@ -482,7 +482,7 @@ class RecipeIndex:
         else:
             try:
                 res = self._kernel_lookup(snap, keys)
-            except (NotImplementedError, ImportError):
+            except NotImplementedError:  # no array export for this index
                 return None
         self.probe_stats["optimistic_probes"] += len(keys)
         # a crash may land between the overlapped probe and its version
@@ -551,8 +551,6 @@ class RecipeIndex:
                                       np.asarray(keys, np.int64))
         except NotImplementedError:  # no array export for this index
             return [self.lookup(int(k)) for k in keys]
-        except ImportError:  # jax-less environment: correct fallback
-            return [self.lookup(int(k)) for k in keys]
         if res is None:  # empty structure: nothing can be found
             return [None] * len(keys)
         found, vals = res
@@ -578,7 +576,7 @@ class RecipeIndex:
         else:
             try:
                 res = self._kernel_lookup(snap, keys[clean_idx])
-            except (NotImplementedError, ImportError):
+            except NotImplementedError:  # no array export for this index
                 return None
         if res is not None:
             found, vals = res
@@ -671,9 +669,6 @@ class RecipeIndex:
                                     np.asarray(start_keys, np.int64),
                                     np.asarray(counts, np.int64))
         except NotImplementedError:  # unordered / no sorted iteration
-            return [self.scan(int(k), c)
-                    for k, c in zip(start_keys, counts)]
-        except ImportError:  # jax-less environment: correct fallback
             return [self.scan(int(k), c)
                     for k, c in zip(start_keys, counts)]
         if res is None:  # empty structure: every scan is empty

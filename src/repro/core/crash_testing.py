@@ -397,7 +397,7 @@ def plan_crash_sweep(
         index._accounted_stores = index._write_account()
         try:
             index.snapshot()
-        except (NotImplementedError, ImportError):
+        except NotImplementedError:
             pass
 
     prime()

@@ -13,15 +13,17 @@ Execution placement:
 * with >= S local devices, the vmapped probe is wrapped in
   ``jax.shard_map`` over a 1-D ``("shard",)`` mesh, so each shard's
   run and queries live on — and are probed by — their own device;
-* otherwise (the portable fallback, and the only path on a 1-device
-  host) the plain ``jax.vmap`` form runs the same program on one
-  device, bit-identical.
+* otherwise (the only path on a 1-device host) the plain ``jax.vmap``
+  form runs the same program on one device, bit-identical.  Which one
+  ran is visible: the ``shard.mesh_lookup`` span carries
+  ``placement`` ("devices" or "one_device").
 
-64-bit keys are handled the same way the Pallas kernels handle them
-(kernels/scan): split into int32 halves with the low half XOR-biased,
-so signed lane compares realize unsigned 64-bit order without
-requiring jax x64 mode.  Found/value semantics are bit-identical to
-``kernels.scan.sorted_lookup`` (lower bound + key-equality check).
+64-bit keys are handled the same way kernels/scan handles them, with
+its ``lower_bound``: split into int32 halves with the low half
+XOR-biased, so signed lane compares realize unsigned 64-bit order
+without requiring jax x64 mode.  Found/value semantics are
+bit-identical to ``kernels.scan.sorted_lookup`` (lower bound +
+key-equality check).
 """
 
 from __future__ import annotations
@@ -33,7 +35,6 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 _BIAS = np.int32(-(1 << 31))
-
 
 @dataclasses.dataclass
 class StackedRuns:
@@ -52,8 +53,10 @@ class StackedRuns:
 def build_stacked(runs: Sequence[Optional[Tuple[np.ndarray, np.ndarray]]]
                   ) -> StackedRuns:
     """Stack per-shard sorted (keys, vals) runs (None = empty shard)
-    into one [S, N] device form, N padded to a common power of two."""
+    into one [S, N] device form, N padded to a common power of two.
+    With a device per shard, row s lives on device s."""
     from ..kernels.probe import split64
+    import jax
     import jax.numpy as jnp
     S = len(runs)
     n_live = [0 if r is None else int(r[0].shape[0]) for r in runs]
@@ -74,58 +77,57 @@ def build_stacked(runs: Sequence[Optional[Tuple[np.ndarray, np.ndarray]]]
         lo, hi = split64(np.asarray(v, np.int64))
         vhi[s, :n_live[s]] = hi
         vlo[s, :n_live[s]] = lo
+    n = np.asarray(n_live, np.int32)
+    if placement(S) == "devices":
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        rows = NamedSharding(shard_mesh(S), P("shard"))
+        put = lambda a: jax.device_put(a, rows)
+    else:
+        put = jnp.asarray
     return StackedRuns(
-        khi=jnp.asarray(khi), klo=jnp.asarray(klo ^ _BIAS),
-        vhi=jnp.asarray(vhi), vlo=jnp.asarray(vlo),
-        n=jnp.asarray(n_live, dtype=jnp.int32), n_pad=n_pad,
-        steps=max(1, n_pad.bit_length()), n_shards=S)
+        khi=put(khi), klo=put(klo ^ _BIAS), vhi=put(vhi), vlo=put(vlo),
+        n=put(n), n_pad=n_pad, steps=max(1, n_pad.bit_length()),
+        n_shards=S)
 
 
 def _probe_one_shard(khi, klo, vhi, vlo, n, qhi, qlo, *, steps: int):
     """Lower bound + equality over ONE shard's run: the per-device
     program ``shard_map``/``vmap`` replicate across the shard axis."""
-    import jax
     import jax.numpy as jnp
-
-    def less(ahi, alo, bhi, blo):
-        # unsigned-64 (a < b) on split halves; low halves pre-biased
-        return (ahi < bhi) | ((ahi == bhi) & (alo < blo))
-
-    lo = jnp.zeros(qhi.shape, jnp.int32)
-    hi = jnp.full(qhi.shape, n, jnp.int32)
-
-    def body(_, lohi):
-        lo, hi = lohi
-        mid = (lo + hi) >> 1
-        go_right = less(khi[mid], klo[mid], qhi, qlo)  # run[mid] < q
-        return jnp.where(go_right, mid + 1, lo), jnp.where(go_right, hi, mid)
-
-    lo, hi = jax.lax.fori_loop(0, steps, body, (lo, hi))
+    from ..kernels.scan import lower_bound
+    lo = lower_bound(khi, klo, n, qhi, qlo, steps=steps)
     pos = jnp.clip(lo, 0, khi.shape[0] - 1)
     found = (lo < n) & (khi[pos] == qhi) & (klo[pos] == qlo)
     return found, jnp.where(found, vhi[pos], 0), jnp.where(found, vlo[pos], 0)
 
 
+@functools.lru_cache(maxsize=8)
+def shard_mesh(n_shards: int):
+    """The 1-D ``("shard",)`` mesh over the first ``n_shards`` devices."""
+    import jax
+    return jax.make_mesh((n_shards,), ("shard",))
+
+
 @functools.lru_cache(maxsize=32)
-def _compiled_probe(n_shards: int, steps: int, use_shard_map: bool):
+def compiled_probe(steps: int, mesh=None):
+    """The jitted all-shard probe over ``[S, ...]`` stacked inputs: the
+    vmapped per-shard search, under ``shard_map`` on ``mesh`` (one
+    device per shard) when one is given."""
     import jax
     fn = jax.vmap(functools.partial(_probe_one_shard, steps=steps))
-    if use_shard_map:
+    if mesh is not None:
         from jax.sharding import PartitionSpec as P
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:  # pre-0.6 spelling
-            from jax.experimental.shard_map import shard_map
-        mesh = jax.make_mesh((n_shards,), ("shard",))
         spec = P("shard")
-        fn = shard_map(fn, mesh=mesh, in_specs=(spec,) * 7,
-                       out_specs=(spec, spec, spec))
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 7,
+                           out_specs=(spec, spec, spec))
     return jax.jit(fn)
 
 
-def mesh_devices(n_shards: int) -> bool:
-    """True when a real 1-D device mesh of ``n_shards`` is available."""
+def placement(n_shards: int) -> str:
+    """``"devices"`` when a real 1-D device mesh of ``n_shards`` is
+    available (one device per shard), else ``"one_device"``."""
     import jax
-    return len(jax.devices()) >= n_shards > 1
+    return "devices" if len(jax.devices()) >= n_shards > 1 else "one_device"
 
 
 def mesh_lookup(stacked: StackedRuns,
@@ -150,7 +152,8 @@ def mesh_lookup(stacked: StackedRuns,
             lo, hi = split64(np.asarray(q, np.int64))
             qhi[s, :q_len[s]] = hi
             qlo[s, :q_len[s]] = lo
-    fn = _compiled_probe(S, stacked.steps, mesh_devices(S))
+    fn = compiled_probe(stacked.steps, shard_mesh(S)
+                        if placement(S) == "devices" else None)
     found, vhi, vlo = fn(stacked.khi, stacked.klo, stacked.vhi, stacked.vlo,
                          stacked.n, jnp.asarray(qhi),
                          jnp.asarray(qlo ^ _BIAS))
@@ -159,4 +162,5 @@ def mesh_lookup(stacked: StackedRuns,
     return [(found[s, :q_len[s]], vals[s, :q_len[s]]) for s in range(S)]
 
 
-__all__ = ["StackedRuns", "build_stacked", "mesh_devices", "mesh_lookup"]
+__all__ = ["StackedRuns", "build_stacked", "compiled_probe",
+           "mesh_lookup", "placement", "shard_mesh"]

@@ -168,11 +168,8 @@ class ShardedIndex:
         result.route_ns = time.perf_counter_ns() - t0
         use_mesh = self.mesh_reads if mesh is None else mesh
         if use_mesh and n >= self.n_shards and bool((kinds == GET).all()):
-            try:
-                self._execute_mesh(keys, parts, result, collect_results)
-                return result
-            except ImportError:
-                pass  # jax-less host: the per-shard path is always there
+            self._execute_mesh(keys, parts, result, collect_results)
+            return result
         self._execute_per_shard(kinds, keys, aux, parts, result,
                                 force_kernel, collect_results)
         return result
@@ -299,7 +296,7 @@ class ShardedIndex:
 
     def _execute_mesh(self, keys, parts, result,
                       collect_results: bool) -> None:
-        from .mesh import build_stacked, mesh_lookup
+        from .mesh import build_stacked, mesh_lookup, placement
         ek = tuple(sh._epoch_key() for sh in self.shards)
         if self._mesh_cache is None or self._mesh_cache[0] != ek:
             runs = []
@@ -316,7 +313,8 @@ class ShardedIndex:
         stacked = self._mesh_cache[1]
         t0 = time.perf_counter_ns()
         with _OBS.span("shard.mesh_lookup", shards=self.n_shards,
-                       ops=int(keys.shape[0])):
+                       ops=int(keys.shape[0]),
+                       placement=placement(self.n_shards)):
             per_shard = mesh_lookup(stacked, [keys[idx] for idx in parts])
         dt = time.perf_counter_ns() - t0
         # one fused dispatch covers all shards: book each shard's share

@@ -1,5 +1,7 @@
-"""Pallas TPU kernels for the framework's compute hot spots.
+"""Device code for the framework's hot spots.
 
-Each kernel directory has kernel.py (pl.pallas_call + explicit
-BlockSpec VMEM tiling), ops.py (jit'd public wrapper), and ref.py (the
-pure-jnp oracle it is validated against in interpret mode)."""
+Each kernel directory has kernel.py (the device program: a
+``pl.pallas_call`` with BlockSpec VMEM tiling, or XLA code where the
+data lives in HBM — ``scan``, ``art_probe``), ops.py (the host wrapper),
+and ref.py (the oracle it is validated against).  ``backend.interpret``
+is the one interpret-or-compile decision for every Pallas kernel."""

@@ -1,12 +1,12 @@
-"""Batched radix descent (P-ART and P-HOT) — Pallas TPU kernel.
+"""Batched radix descent (P-ART and P-HOT) — XLA code over HBM pages.
 
-A tile of queries descends the exported node pages together: at each
+A batch of queries descends the exported node pages together: at each
 step, every lane gathers its current node's ``level`` word, picks the
 key *unit* at that level, and hops through the node's child row.  The
 unit width is set by the export: P-ART uses 8-bit units (qunits
 [Q, 8], children [N, 256], at most 9 steps), P-HOT's nibble-span
 compound nodes use 4-bit units (qunits [Q, 16], children [N, 16], at
-most 17 steps) — the kernel derives both from the array shapes.
+most 17 steps) — the descent derives both from the array shapes.
 
 Trusting ``level`` is exactly the scalar reader's stale-prefix
 tolerance (paper §6.4): a node whose prefix header was left stale by an
@@ -15,51 +15,41 @@ interrupted path-compression SMO is traversed by level and the full
 bit-identical to scalar ``lookup`` even mid-SMO or post-crash.
 Keys/values travel as (lo, hi) int32 halves.
 
-The node pages (children, level, leaf words) are broadcast to every
-grid step; queries are tiled.  Like the other kernels this runs
-interpret-mode by default (the gathers lower to dynamic-slice chains on
-real TPU backends; interpret executes them directly on CPU).
+The node pages stay in HBM and XLA does the data-dependent gathers
+(``children[node, unit]``): the pages of a deployment-size tree do not
+fit a kernel's fast memory, and the TPU's Pallas compiler refuses
+gathers with data-dependent indices.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-# sized to swallow a whole batch per grid step in interpret mode — the
-# node-page broadcast and the fixed per-step cost are paid once
-QUERY_BLOCK = 4096
 KEY_BYTES = 8
 
 
-def _descend_kernel(qbytes_ref, qlo_ref, qhi_ref, qfp_ref, children_ref,
-                    level_ref, is_leaf_ref, lfp_ref, lklo_ref, lkhi_ref,
-                    lvlo_ref, lvhi_ref, found_ref, olo_ref, ohi_ref,
-                    nenc_ref, nfp_ref, nfalse_ref):
-    qbytes = qbytes_ref[...]          # [QB, KEY_BYTES]
-    qlo = qlo_ref[...][:, 0]          # [QB]
-    qhi = qhi_ref[...][:, 0]
-    qfp = qfp_ref[...][:, 0]
-    children = children_ref[...]      # [N, 256]
-    level = level_ref[...][:, 0]      # [N]
-    is_leaf = is_leaf_ref[...][:, 0]
-    lfp = lfp_ref[...][:, 0]          # partial-key fingerprint lane
-    lklo = lklo_ref[...][:, 0]
-    lkhi = lkhi_ref[...][:, 0]
-    lvlo = lvlo_ref[...][:, 0]
-    lvhi = lvhi_ref[...][:, 0]
-    QB, U = qbytes.shape  # U key units per key (8 bytes or 16 nibbles)
-    node = jnp.zeros((QB,), jnp.int32)  # node 0 is the root
-    active = jnp.ones((QB,), jnp.bool_)
-    found = jnp.zeros((QB,), jnp.bool_)
-    olo = jnp.zeros((QB,), jnp.int32)
-    ohi = jnp.zeros((QB,), jnp.int32)
-    nenc = jnp.zeros((QB,), jnp.int32)    # leaf encounters (fp compares)
-    nfp = jnp.zeros((QB,), jnp.int32)     # fingerprint matches
-    nfalse = jnp.zeros((QB,), jnp.int32)  # matches the full key rejects
+@jax.jit
+def art_descend(qbytes, qlo, qhi, qfp, children, level, is_leaf, lfp,
+                lklo, lkhi, lvlo, lvhi):
+    """qbytes: [Q, U] int32 big-endian key units (U=8 bytes for P-ART,
+    U=16 nibbles for P-HOT); qlo/qhi: [Q] int32 key halves; qfp: [Q]
+    int32 partial-key fingerprints (fingerprint.fp_partial); children:
+    [N, 2**unit_bits] int32 (-1 none); level/is_leaf/lfp/leaf key-value
+    halves: [N] int32 (lfp is the export's ``leaf_fp`` lane, 0 for
+    non-leaf rows).  Returns (found [Q] bool, value_lo, value_hi [Q]
+    int32, n_leaf_checks, n_fp_match, n_fp_false [Q] int32) — found and
+    values are unchanged by the fingerprint pre-pass; the counts feed
+    the probe-traffic model."""
+    Q, U = qbytes.shape  # U key units per key (8 bytes or 16 nibbles)
+    node = jnp.zeros((Q,), jnp.int32)  # node 0 is the root
+    active = jnp.ones((Q,), jnp.bool_)
+    found = jnp.zeros((Q,), jnp.bool_)
+    olo = jnp.zeros((Q,), jnp.int32)
+    ohi = jnp.zeros((Q,), jnp.int32)
+    nenc = jnp.zeros((Q,), jnp.int32)    # leaf encounters (fp compares)
+    nfp = jnp.zeros((Q,), jnp.int32)     # fingerprint matches
+    nfalse = jnp.zeros((Q,), jnp.int32)  # matches the full key rejects
     # levels strictly increase along any path, so U internal hops + the
     # leaf check bound the descent; finished lanes just idle
     for _ in range(U + 1):
@@ -84,53 +74,4 @@ def _descend_kernel(qbytes_ref, qlo_ref, qhi_ref, qfp_ref, children_ref,
         child = children[node, byte]
         active = active & (child >= 0)
         node = jnp.where(active, child, node)
-    found_ref[...] = found[:, None]
-    olo_ref[...] = olo[:, None]
-    ohi_ref[...] = ohi[:, None]
-    nenc_ref[...] = nenc[:, None]
-    nfp_ref[...] = nfp[:, None]
-    nfalse_ref[...] = nfalse[:, None]
-
-
-@functools.partial(jax.jit, static_argnames=("query_block", "interpret"))
-def art_descend(qbytes, qlo, qhi, qfp, children, level, is_leaf, lfp,
-                lklo, lkhi, lvlo, lvhi, *,
-                query_block: int = QUERY_BLOCK, interpret: bool = True):
-    """qbytes: [Q, U] int32 big-endian key units (U=8 bytes for P-ART,
-    U=16 nibbles for P-HOT); qlo/qhi: [Q] int32 key halves; qfp: [Q]
-    int32 partial-key fingerprints (fingerprint.fp_partial); children:
-    [N, 2**unit_bits] int32 (-1 none); level/is_leaf/lfp/leaf key-value
-    halves: [N] int32 (lfp is the export's ``leaf_fp`` lane, 0 for
-    non-leaf rows).  Returns (found [Q] bool, value_lo, value_hi [Q]
-    int32, n_leaf_checks, n_fp_match, n_fp_false [Q] int32) — found and
-    values are unchanged by the fingerprint pre-pass; the counts feed
-    the probe-traffic model."""
-    Q, U = qbytes.shape
-    N, fan = children.shape
-    qb = min(query_block, Q)
-    assert Q % qb == 0, (Q, qb)
-    grid = (Q // qb,)
-    qtile = lambda w: pl.BlockSpec((qb, w), lambda i: (i, 0))
-    bcast = lambda w: pl.BlockSpec((N, w), lambda i: (0, 0))
-    col = lambda a: a.reshape(-1, 1)
-    found, olo, ohi, nenc, nfp, nfalse = pl.pallas_call(
-        _descend_kernel,
-        grid=grid,
-        in_specs=[qtile(U), qtile(1), qtile(1), qtile(1),
-                  bcast(fan), bcast(1), bcast(1), bcast(1),
-                  bcast(1), bcast(1), bcast(1), bcast(1)],
-        out_specs=[qtile(1), qtile(1), qtile(1),
-                   qtile(1), qtile(1), qtile(1)],
-        out_shape=[
-            jax.ShapeDtypeStruct((Q, 1), jnp.bool_),
-            jax.ShapeDtypeStruct((Q, 1), jnp.int32),
-            jax.ShapeDtypeStruct((Q, 1), jnp.int32),
-            jax.ShapeDtypeStruct((Q, 1), jnp.int32),
-            jax.ShapeDtypeStruct((Q, 1), jnp.int32),
-            jax.ShapeDtypeStruct((Q, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(qbytes, col(qlo), col(qhi), col(qfp), children, col(level),
-      col(is_leaf), col(lfp), col(lklo), col(lkhi), col(lvlo), col(lvhi))
-    return (found[:, 0], olo[:, 0], ohi[:, 0],
-            nenc[:, 0], nfp[:, 0], nfalse[:, 0])
+    return found, olo, ohi, nenc, nfp, nfalse

@@ -1,9 +1,9 @@
-"""Host wrapper: radix node-page exports -> art_descend kernel calls.
+"""Host wrapper: radix node-page exports -> art_descend calls.
 
 Splits 64-bit leaf words into int32 halves, extracts big-endian key
 units (8-bit bytes for P-ART, 4-bit nibbles for P-HOT — the export's
-``unit_bits`` field selects), pads the query batch to a whole number of
-kernel blocks, and recombines the halves of the result.
+``unit_bits`` field selects), pads the query batch to a small family of
+shapes, and recombines the halves of the result.
 
 The descent carries the export's ``leaf_fp`` partial-key fingerprint
 lane: each leaf's inline byte is compared before the full 64-bit key
@@ -22,7 +22,7 @@ import numpy as np
 from ...obs import RECORDER as _OBS
 from ..probe import combine64, pad_queries, split64
 from ..probe.fingerprint import account, fp_partial
-from .kernel import QUERY_BLOCK, art_descend
+from .kernel import art_descend
 from .ref import leaf_fp_lane
 
 KEY_BYTES = 8
@@ -59,8 +59,8 @@ def _prepare(arrays: Dict[str, np.ndarray]) -> tuple:
 
 
 def _descend(queries: np.ndarray, pages: tuple, *,
-             fingerprints: bool = True, stats: Optional[dict] = None,
-             interpret: bool) -> Tuple[np.ndarray, np.ndarray]:
+             fingerprints: bool = True, stats: Optional[dict] = None
+             ) -> Tuple[np.ndarray, np.ndarray]:
     unit_bits, *node_pages = pages
     q = np.asarray(queries, np.int64)
     Q = q.shape[0]
@@ -70,13 +70,11 @@ def _descend(queries: np.ndarray, pages: tuple, *,
                    fingerprints=fingerprints) as sp:
         if pad:
             q = np.pad(q, (0, pad))  # padded lanes miss at the leaf check
-        qb = min(QUERY_BLOCK, q.shape[0])
         qlo, qhi = split64(q)
         qfp = fp_partial(q).astype(np.int32)
         found, olo, ohi, nenc, nfp, nfalse = art_descend(
             jnp.asarray(key_units(q, unit_bits)), jnp.asarray(qlo),
-            jnp.asarray(qhi), jnp.asarray(qfp), *node_pages, query_block=qb,
-            interpret=interpret)
+            jnp.asarray(qhi), jnp.asarray(qfp), *node_pages)
         found = np.asarray(found)[:Q]
         values = combine64(np.asarray(olo)[:Q], np.asarray(ohi)[:Q])
         # lanes = leaves actually reached (the radix descent has no
@@ -96,17 +94,17 @@ def _descend(queries: np.ndarray, pages: tuple, *,
 
 
 def batched_lookup(queries: np.ndarray, arrays: Dict[str, np.ndarray], *,
-                   fingerprints: bool = True, stats: Optional[dict] = None,
-                   interpret: bool = True) -> Tuple[np.ndarray, np.ndarray]:
+                   fingerprints: bool = True, stats: Optional[dict] = None
+                   ) -> Tuple[np.ndarray, np.ndarray]:
     """queries: [Q] int64; arrays: PART/PHOT export_arrays output.
     Returns (found [Q] bool, values [Q] int64), bit-identical to the
     scalar ``lookup`` against the same snapshot."""
     return _descend(queries, _prepare(arrays), fingerprints=fingerprints,
-                    stats=stats, interpret=interpret)
+                    stats=stats)
 
 
 def snapshot_lookup(snap, queries: np.ndarray, *, fingerprints: bool = True,
-                    stats: Optional[dict] = None, interpret: bool = True
+                    stats: Optional[dict] = None
                     ) -> Tuple[np.ndarray, np.ndarray]:
     """Batched lookup against an ``IndexSnapshot`` of PART or PHOT node
     pages; the split + device conversion is memoized on the snapshot."""
@@ -114,5 +112,4 @@ def snapshot_lookup(snap, queries: np.ndarray, *, fingerprints: bool = True,
     if pages is None:
         pages = _prepare(snap.arrays)
         snap.cache["art_probe"] = pages
-    return _descend(queries, pages, fingerprints=fingerprints, stats=stats,
-                    interpret=interpret)
+    return _descend(queries, pages, fingerprints=fingerprints, stats=stats)

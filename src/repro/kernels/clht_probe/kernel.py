@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import backend
+
 QUERY_BLOCK = 256
 
 
@@ -36,7 +38,7 @@ def _probe_kernel(q_ref, bk_ref, bv_ref, found_ref, val_ref):
 
 
 def clht_probe(queries, bucket_keys, bucket_vals, *,
-               query_block: int = QUERY_BLOCK, interpret: bool = True):
+               query_block: int = QUERY_BLOCK):
     """queries: [Q] int64-as-int32-pairs? — int32 keys for the kernel
     (the 64-bit control plane hashes down to 32-bit tags for the data
     plane; tag collisions re-verify against the authoritative index).
@@ -62,6 +64,6 @@ def clht_probe(queries, bucket_keys, bucket_vals, *,
             jax.ShapeDtypeStruct((Q, 1), jnp.bool_),
             jax.ShapeDtypeStruct((Q, 1), bucket_vals.dtype),
         ],
-        interpret=interpret,
+        interpret=backend.interpret(),
     )(queries.reshape(Q, 1), bucket_keys, bucket_vals)
     return found[:, 0], vals[:, 0]

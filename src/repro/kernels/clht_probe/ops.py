@@ -24,7 +24,7 @@ import numpy as np
 from ...obs import RECORDER as _OBS
 from ..probe import combine64, pad_queries, probe64_lookup, split64
 from ..probe.fingerprint import account, fp64
-from ..probe.kernel import QUERY_BLOCK, probe64, probe64_fp
+from ..probe.kernel import probe64, probe64_fp
 from .kernel import clht_probe
 
 SLOTS = 3
@@ -44,8 +44,8 @@ def mix64(keys: np.ndarray) -> np.ndarray:
 def batched_lookup(queries: np.ndarray, keys: np.ndarray, vals: np.ndarray,
                    nxt: np.ndarray, *, n_buckets: int,
                    fps: Optional[np.ndarray] = None, fingerprints: bool = True,
-                   stats: Optional[dict] = None,
-                   interpret: bool = True) -> Tuple[np.ndarray, np.ndarray]:
+                   stats: Optional[dict] = None
+                   ) -> Tuple[np.ndarray, np.ndarray]:
     """queries: [Q] int64; keys/vals: [R, SLOTS] int64 bucket-major slot
     arrays; nxt: [R] int64 chain row index (-1 none); fps: [R, SLOTS]
     uint8 fingerprint lane — the layout of PCLHT.export_arrays.
@@ -54,13 +54,12 @@ def batched_lookup(queries: np.ndarray, keys: np.ndarray, vals: np.ndarray,
     bucket = (mix64(q) % _U64(n_buckets)).astype(np.int64)
     return probe64_lookup(q, bucket, np.asarray(nxt, np.int64),
                           keys, vals, fps=fps, fingerprints=fingerprints,
-                          stats=stats, interpret=interpret)
+                          stats=stats)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("depth", "use_fp", "interpret"))
+@functools.partial(jax.jit, static_argnames=("depth", "use_fp"))
 def _gather_probe(bucket, qlo, qhi, qfp, klo, khi, vlo, vhi, fps, nxt, *,
-                  depth: int, use_fp: bool, interpret: bool):
+                  depth: int, use_fp: bool):
     """Fused probe: the XLA gather chases each query's overflow chain
     (``depth`` = the snapshot's longest chain) and feeds the windows
     straight to the probe64 kernel — nothing materializes on the host.
@@ -77,15 +76,13 @@ def _gather_probe(bucket, qlo, qhi, qfp, klo, khi, vlo, vhi, fps, nxt, *,
         parts = [jnp.where(r[:, None] >= 0, arr[jnp.maximum(r, 0)], 0)
                  for r in rows]
         windows.append(jnp.concatenate(parts, axis=1))
-    qb = min(QUERY_BLOCK, qlo.shape[0])
     if use_fp:
-        return probe64_fp(qlo, qhi, qfp, *windows, query_block=qb,
-                          interpret=interpret)
-    return probe64(qlo, qhi, *windows, query_block=qb, interpret=interpret)
+        return probe64_fp(qlo, qhi, qfp, *windows)
+    return probe64(qlo, qhi, *windows)
 
 
 def snapshot_lookup(snap, queries: np.ndarray, *, fingerprints: bool = True,
-                    stats: Optional[dict] = None, interpret: bool = True
+                    stats: Optional[dict] = None
                     ) -> Tuple[np.ndarray, np.ndarray]:
     """Batched lookup against an ``IndexSnapshot`` of PCLHT arrays.
 
@@ -126,7 +123,7 @@ def snapshot_lookup(snap, queries: np.ndarray, *, fingerprints: bool = True,
         out = _gather_probe(
             jnp.asarray(bucket), jnp.asarray(qlo), jnp.asarray(qhi),
             jnp.asarray(qfp), *halves, fps_dev, nxt_dev, depth=depth,
-            use_fp=fingerprints, interpret=interpret)
+            use_fp=fingerprints)
         found, olo, ohi = out[:3]
         found = np.asarray(found)[:Q]
         values = combine64(np.asarray(olo)[:Q], np.asarray(ohi)[:Q])
@@ -143,9 +140,8 @@ def snapshot_lookup(snap, queries: np.ndarray, *, fingerprints: bool = True,
     return found, np.where(found, values, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("n_buckets", "interpret"))
-def tag_lookup(queries, keys, vals, nxt, *, n_buckets: int,
-               interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("n_buckets",))
+def tag_lookup(queries, keys, vals, nxt, *, n_buckets: int):
     """The original 32-bit-tag data plane: queries hashed with a 32-bit
     mix, one lane per key, fixed CHAIN_DEPTH window.  Collisions must be
     re-verified against the authoritative index."""
@@ -170,4 +166,4 @@ def tag_lookup(queries, keys, vals, nxt, *, n_buckets: int,
     bv = jnp.concatenate(window_v, axis=1)
     bk = jnp.pad(bk, ((0, 0), (0, pad)))
     bv = jnp.pad(bv, ((0, 0), (0, pad)))
-    return clht_probe(queries, bk, bv, interpret=interpret)
+    return clht_probe(queries, bk, bv)

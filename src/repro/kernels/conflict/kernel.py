@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import backend
 from .ref import DELETE, GET, PUT, SCAN, UPDATE
 
 NONE = 5  # padding kind: conflicts with nothing
@@ -60,11 +61,10 @@ def _conflict_any_kernel(ak_ref, alo_ref, ahi_ref, bk_ref, blo_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("writes_conflict",
-                                             "cand_block", "interpret"))
+                                             "cand_block"))
 def conflict_any_kernel(a_kinds, a_klo, a_khi, b_kinds, b_klo, b_khi, *,
                         writes_conflict: bool = False,
-                        cand_block: int = CAND_BLOCK,
-                        interpret: bool = True):
+                        cand_block: int = CAND_BLOCK):
     """a_*: [A] int32 candidate kinds + key halves; b_*: [B] reference
     set.  Returns [A] int32 0/1: candidate conflicts with some b op."""
     A, B = a_kinds.shape[0], b_kinds.shape[0]
@@ -79,7 +79,7 @@ def conflict_any_kernel(a_kinds, a_klo, a_khi, b_kinds, b_klo, b_khi, *,
         in_specs=[col, col, col, row, row, row],
         out_specs=col,
         out_shape=jax.ShapeDtypeStruct((A, 1), jnp.int32),
-        interpret=interpret,
+        interpret=backend.interpret(),
     )(a_kinds.reshape(A, 1), a_klo.reshape(A, 1), a_khi.reshape(A, 1),
       b_kinds.reshape(1, B), b_klo.reshape(1, B), b_khi.reshape(1, B))
     return out[:, 0]

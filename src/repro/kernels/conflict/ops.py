@@ -30,8 +30,8 @@ def _pad_pow2(n: int, block: int) -> int:
 
 
 def conflict_any(kinds_a, keys_a, kinds_b, keys_b, *,
-                 writes_conflict: bool = False, use_kernel: bool = False,
-                 interpret: bool = True) -> np.ndarray:
+                 writes_conflict: bool = False, use_kernel: bool = False
+                 ) -> np.ndarray:
     """[A] bool: does each candidate op conflict with any reference op."""
     kinds_a = np.asarray(kinds_a, np.int32)
     kinds_b = np.asarray(kinds_b, np.int32)
@@ -55,7 +55,7 @@ def conflict_any(kinds_a, keys_a, kinds_b, keys_b, *,
         out = conflict_any_kernel(
             jnp.asarray(ka), jnp.asarray(alo), jnp.asarray(ahi),
             jnp.asarray(kb), jnp.asarray(blo), jnp.asarray(bhi),
-            writes_conflict=writes_conflict, interpret=interpret)
+            writes_conflict=writes_conflict)
         return np.asarray(out)[:A].astype(bool)
 
 

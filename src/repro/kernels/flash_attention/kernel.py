@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import backend
+
 DEFAULT_Q_BLOCK = 512
 DEFAULT_KV_BLOCK = 512
 NEG_INF = -1e30
@@ -90,8 +92,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, window: Optional[int] = None,
                     q_block: int = DEFAULT_Q_BLOCK,
-                    kv_block: int = DEFAULT_KV_BLOCK,
-                    interpret: bool = True) -> jnp.ndarray:
+                    kv_block: int = DEFAULT_KV_BLOCK) -> jnp.ndarray:
     """q: [BH, T, dh]; k,v: [BH, S, dh] (batch and heads pre-folded,
     kv heads pre-repeated).  Returns [BH, T, dh]."""
     BH, T, dh = q.shape
@@ -119,5 +120,5 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((q_block, 1), jnp.float32),
             pltpu.VMEM((q_block, dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=backend.interpret(),
     )(q, k, v)

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import backend
+
 
 def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, o_ref, h_ref, *,
                 chunk: int):
@@ -55,7 +57,7 @@ def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, o_ref, h_ref, *,
     h_ref[...] = jnp.exp(total) * h_ref[...] + h_new
 
 
-def ssd(x, dt, B_, C_, A, *, chunk: int = 128, interpret: bool = True):
+def ssd(x, dt, B_, C_, A, *, chunk: int = 128):
     """x: [BH,T,dh]; dt: [BH,T]; B_,C_: [BH,T,N]; A: [BH] (<0).
     Returns y: [BH,T,dh]."""
     BH, T, dh = x.shape
@@ -77,5 +79,5 @@ def ssd(x, dt, B_, C_, A, *, chunk: int = 128, interpret: bool = True):
         out_specs=pl.BlockSpec((1, chunk, dh), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, dh), x.dtype),
         scratch_shapes=[pltpu.VMEM((dh, N), jnp.float32)],
-        interpret=interpret,
+        interpret=backend.interpret(),
     )(x, dt[..., None], B_, C_, A.reshape(BH, 1))

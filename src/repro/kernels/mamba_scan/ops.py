@@ -10,9 +10,8 @@ import jax.numpy as jnp
 from .kernel import ssd
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_heads(xh, dt, B_, C_, A, *, chunk: int = 128,
-              interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def ssd_heads(xh, dt, B_, C_, A, *, chunk: int = 128):
     """xh: [B,T,H,dh]; dt: [B,T,H]; B_,C_: [B,T,N]; A: [H].
     Returns [B,T,H,dh] (B_/C_ shared across heads, as in Mamba)."""
     B, T, H, dh = xh.shape
@@ -22,5 +21,5 @@ def ssd_heads(xh, dt, B_, C_, A, *, chunk: int = 128,
     Bf = jnp.broadcast_to(B_[:, None], (B, H, T, N)).reshape(B * H, T, N)
     Cf = jnp.broadcast_to(C_[:, None], (B, H, T, N)).reshape(B * H, T, N)
     Af = jnp.broadcast_to(A[None], (B, H)).reshape(B * H)
-    y = ssd(xf, dtf, Bf, Cf, Af, chunk=chunk, interpret=interpret)
+    y = ssd(xf, dtf, Bf, Cf, Af, chunk=chunk)
     return y.reshape(B, H, T, dh).transpose(0, 2, 1, 3)

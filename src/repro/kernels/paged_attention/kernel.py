@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import backend
+
 NEG_INF = -1e30
 
 
@@ -65,8 +67,7 @@ def _paged_kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, :, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-def paged_attention(q, kv_pages_k, kv_pages_v, block_table, seq_lens, *,
-                    interpret: bool = True):
+def paged_attention(q, kv_pages_k, kv_pages_v, block_table, seq_lens):
     """q: [B,H,dh]; kv pages: [NP,PS,H,dh]; block_table: [B,MAXP];
     seq_lens: [B].  Returns [B,H,dh]."""
     B, H, dh = q.shape
@@ -102,7 +103,7 @@ def paged_attention(q, kv_pages_k, kv_pages_v, block_table, seq_lens, *,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, 1, dh), q.dtype),
-        interpret=interpret,
+        interpret=backend.interpret(),
     )(block_table, seq_lens, q.reshape(B * H, 1, dh), kv_pages_k,
       kv_pages_v)
     return out.reshape(B, H, dh)

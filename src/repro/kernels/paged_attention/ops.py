@@ -2,17 +2,14 @@
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
 from .kernel import paged_attention
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_mqa(q, pages_k, pages_v, block_table, seq_lens, *,
-              interpret: bool = True):
+@jax.jit
+def paged_mqa(q, pages_k, pages_v, block_table, seq_lens):
     """q: [B,H,dh]; pages_*: [NP,PS,Hk,dh] with H % Hk == 0."""
     B, H, dh = q.shape
     Hk = pages_k.shape[2]
@@ -20,5 +17,4 @@ def paged_mqa(q, pages_k, pages_v, block_table, seq_lens, *,
     if rep > 1:
         pages_k = jnp.repeat(pages_k, rep, axis=2)
         pages_v = jnp.repeat(pages_v, rep, axis=2)
-    return paged_attention(q, pages_k, pages_v, block_table, seq_lens,
-                           interpret=interpret)
+    return paged_attention(q, pages_k, pages_v, block_table, seq_lens)

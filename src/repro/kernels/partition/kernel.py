@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import backend
+
 SHARD_BLOCK = 4096  # queries per grid step (matches the probe kernels)
 
 
@@ -97,10 +99,9 @@ def _route_kernel(klo_ref, khi_ref, out_ref, *, bits: int, scheme: str):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("bits", "scheme", "query_block",
-                                    "interpret"))
+                   static_argnames=("bits", "scheme", "query_block"))
 def shard_route(klo, khi, *, bits: int, scheme: str = "hash",
-                query_block: int = SHARD_BLOCK, interpret: bool = True):
+                query_block: int = SHARD_BLOCK):
     """klo/khi: [Q] int32 key halves; returns [Q] int32 shard ids in
     [0, 2^bits).  ``scheme`` is 'hash' (splitmix64 top bits),
     'prefix' (key top bits), or 'prefix@<m>' (bits [m, m+1-bits) —
@@ -116,6 +117,6 @@ def shard_route(klo, khi, *, bits: int, scheme: str = "hash",
         in_specs=[col, col],
         out_specs=col,
         out_shape=jax.ShapeDtypeStruct((Q, 1), jnp.int32),
-        interpret=interpret,
+        interpret=backend.interpret(),
     )(klo.reshape(Q, 1), khi.reshape(Q, 1))
     return out[:, 0]

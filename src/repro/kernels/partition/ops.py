@@ -21,8 +21,7 @@ from .ref import mix64_ref, partition_ref, route_ref
 
 
 def route_shards(keys: np.ndarray, n_shards: int, scheme: str = "hash", *,
-                 use_kernel: bool = False,
-                 interpret: bool = True) -> np.ndarray:
+                 use_kernel: bool = False) -> np.ndarray:
     """Shard id per key: [Q] int32 in [0, n_shards)."""
     keys = np.asarray(keys, np.int64)
     if not use_kernel or keys.size == 0:
@@ -43,7 +42,7 @@ def route_shards(keys: np.ndarray, n_shards: int, scheme: str = "hash", *,
     lo, hi = split64(q)
     import jax.numpy as jnp
     out = shard_route(jnp.asarray(lo), jnp.asarray(hi), bits=bits,
-                      scheme=scheme, interpret=interpret)
+                      scheme=scheme)
     return np.asarray(out)[:Q]
 
 

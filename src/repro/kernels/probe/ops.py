@@ -89,8 +89,7 @@ def pad_queries(n: int, block: int = QUERY_BLOCK) -> int:
 
 def probe64_windows(queries: np.ndarray, split_windows: Sequence[np.ndarray],
                     *, fp_window: Optional[np.ndarray] = None,
-                    fingerprints: bool = True, stats: Optional[dict] = None,
-                    interpret: bool = True
+                    fingerprints: bool = True, stats: Optional[dict] = None
                     ) -> Tuple[np.ndarray, np.ndarray]:
     """Run probe64 over pre-gathered, pre-split windows.
 
@@ -119,7 +118,6 @@ def probe64_windows(queries: np.ndarray, split_windows: Sequence[np.ndarray],
             klo, khi, vlo, vhi = (np.pad(w, ((0, pad), (0, 0)))
                                   for w in (klo, khi, vlo, vhi))
         qlo, qhi = split64(queries)
-        qb = min(QUERY_BLOCK, qlo.shape[0])
         if use_fp:
             if pad:
                 fp_window = np.pad(fp_window, ((0, pad), (0, 0)))
@@ -127,13 +125,11 @@ def probe64_windows(queries: np.ndarray, split_windows: Sequence[np.ndarray],
             found, olo, ohi, nfp, nfalse = probe64_fp(
                 jnp.asarray(qlo), jnp.asarray(qhi), jnp.asarray(qfp),
                 jnp.asarray(klo), jnp.asarray(khi), jnp.asarray(vlo),
-                jnp.asarray(vhi), jnp.asarray(fp_window.astype(np.int32)),
-                query_block=qb, interpret=interpret)
+                jnp.asarray(vhi), jnp.asarray(fp_window.astype(np.int32)))
         else:
             found, olo, ohi = probe64(
                 jnp.asarray(qlo), jnp.asarray(qhi), jnp.asarray(klo),
-                jnp.asarray(khi), jnp.asarray(vlo), jnp.asarray(vhi),
-                query_block=qb, interpret=interpret)
+                jnp.asarray(khi), jnp.asarray(vlo), jnp.asarray(vhi))
         found = np.asarray(found)[:Q]
         values = combine64(np.asarray(olo)[:Q], np.asarray(ohi)[:Q])
         if use_fp:
@@ -153,7 +149,7 @@ def probe64_windows(queries: np.ndarray, split_windows: Sequence[np.ndarray],
 def probe64_lookup(queries: np.ndarray, start: np.ndarray, nxt: np.ndarray,
                    keys: np.ndarray, vals: np.ndarray, *,
                    fps: Optional[np.ndarray] = None, fingerprints: bool = True,
-                   stats: Optional[dict] = None, interpret: bool = True
+                   stats: Optional[dict] = None
                    ) -> Tuple[np.ndarray, np.ndarray]:
     """Gather chain windows from int64 slot arrays and run probe64.
 
@@ -171,5 +167,4 @@ def probe64_lookup(queries: np.ndarray, start: np.ndarray, nxt: np.ndarray,
     windows = gather_chain_windows(start, nxt, slot_arrays)
     fpw = windows[4] if fps is not None else None
     return probe64_windows(queries, windows[:4], fp_window=fpw,
-                           fingerprints=fingerprints, stats=stats,
-                           interpret=interpret)
+                           fingerprints=fingerprints, stats=stats)

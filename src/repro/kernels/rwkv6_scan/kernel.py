@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import backend
+
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *,
                 chunk: int):
@@ -57,8 +59,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *,
     s_ref[...] = jnp.exp(total).T * s_ref[...] + s_new
 
 
-def wkv6(r, k, v, logw, u, *, chunk: int = 128,
-         interpret: bool = True):
+def wkv6(r, k, v, logw, u, *, chunk: int = 128):
     """r,k,v,logw: [BH, T, dh]; u: [dh]. Returns o: [BH, T, dh].
 
     NOTE on the intra/decay algebra: exp(cum_t - w_t - cum_s) can
@@ -85,5 +86,5 @@ def wkv6(r, k, v, logw, u, *, chunk: int = 128,
         out_specs=pl.BlockSpec((1, chunk, dh), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, dh), r.dtype),
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
-        interpret=interpret,
+        interpret=backend.interpret(),
     )(r, k, v, logw, u2)

@@ -1,25 +1,31 @@
-"""Batched sorted-page search — Pallas TPU kernel.
+"""Batched sorted-run search — XLA code over HBM-resident runs.
 
 The shared ordered-index read engine: every ordered RECIPE index can
 export its reachable entries as one sorted run of (key, value) pairs
-(the page-major flattening of its leaf pages), and this kernel answers
-a whole tile of queries against that run with a vectorized binary
-search.  Each lane runs the same ceil(log2(N)) lower-bound steps (the
-APEX leaf-probe shape: locate the leaf slot, then read a bounded
-window), then gathers a ``max_count``-wide window of consecutive
-entries starting at its lower bound:
+(the page-major flattening of its leaf pages), and ``scan_window``
+answers a whole batch of queries against that run with a vectorized
+binary search.  Each lane runs the same lower-bound steps (the APEX
+leaf-probe shape: locate the leaf slot, then read a bounded window),
+then gathers a ``max_count``-wide window of consecutive entries
+starting at its lower bound:
 
 * point lookup  = window of 1 + host-side key-equality check;
 * range scan    = window of ``count`` entries (YCSB-E's "scan N
   records from start key").
+
+The run stays in HBM and XLA does the data-dependent gathers: a run of
+deployment size does not fit a kernel's fast memory, and the TPU's
+Pallas compiler refuses gathers with data-dependent indices.
+``lower_bound`` is also the per-shard search of the mesh fan-out
+(``distributed/mesh.py``).
 
 PM words are 64-bit but the VPU lanes are 32-bit, so keys and values
 travel as (lo, hi) int32 halves.  Ordering over split halves needs an
 unsigned compare on the low word, which int32 lanes cannot do directly:
 the host pre-biases ``lo ^ 0x80000000`` so signed lane compares realize
 unsigned 64-bit order (keys are PM words < 2^63, so the high half is
-already order-preserving as a signed int32).  The kernel un-biases
-gathered keys before writing them back.
+already order-preserving as a signed int32).  Gathered keys are
+un-biased before they are returned.
 """
 
 from __future__ import annotations
@@ -28,87 +34,49 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-
-# One grid step per QUERY_BLOCK queries; interpret mode (the default)
-# pays a fixed per-step cost, so the block swallows a whole YCSB batch.
-QUERY_BLOCK = 4096
 
 _BIAS = -(1 << 31)  # XOR bias realizing unsigned int32 order
 
 
-def _scan_kernel(qlo_ref, qhi_ref, cnt_ref, klo_ref, khi_ref, vlo_ref,
-                 vhi_ref, n_ref, valid_ref, oklo_ref, okhi_ref, ovlo_ref,
-                 ovhi_ref, *, steps: int, max_count: int):
-    qlo = qlo_ref[...][:, 0]   # [QB] biased low halves
-    qhi = qhi_ref[...][:, 0]
-    cnt = cnt_ref[...][:, 0]
-    klo = klo_ref[...][:, 0]   # [N] biased low halves, sorted run
-    khi = khi_ref[...][:, 0]
-    vlo = vlo_ref[...][:, 0]
-    vhi = vhi_ref[...][:, 0]
-    n = n_ref[0, 0]            # live entries (N may be padded)
-    QB = qlo.shape[0]
-    N = klo.shape[0]
-    # vectorized lower bound: first index with key >= query
-    lo = jnp.zeros((QB,), jnp.int32)
-    hi = jnp.zeros((QB,), jnp.int32) + n
-    for _ in range(steps):
+def lower_bound(khi, klo, n, qhi, qlo, *, steps: int):
+    """First index in the run's live prefix ``[0, n)`` whose key is >=
+    each query.  khi/klo: [N] key halves (klo biased); qhi/qlo: query
+    halves of any shape; ``steps`` >= ceil(log2(n + 1)).  The carry is
+    built from the queries, so under ``shard_map`` it varies along the
+    same mesh axes as they do."""
+    last = khi.shape[0] - 1
+
+    def body(_, lohi):
+        lo, hi = lohi
         act = lo < hi
-        mid = (lo + hi) // 2
-        safe = jnp.clip(mid, 0, N - 1)
-        mhi = khi[safe]
-        mlo = klo[safe]
+        mid = (lo + hi) >> 1
+        safe = jnp.clip(mid, 0, last)
+        mhi, mlo = khi[safe], klo[safe]
         less = (mhi < qhi) | ((mhi == qhi) & (mlo < qlo))
-        lo = jnp.where(act & less, mid + 1, lo)
-        hi = jnp.where(act & ~less, mid, hi)
-    # window gather: max_count consecutive entries from each lower bound
-    off = jax.lax.broadcasted_iota(jnp.int32, (QB, max_count), 1)
-    pos = lo[:, None] + off
-    ok = (off < cnt[:, None]) & (pos < n)
-    safe = jnp.clip(pos, 0, N - 1)
-    valid_ref[...] = ok
-    oklo_ref[...] = jnp.where(ok, klo[safe] ^ _BIAS, 0)  # un-bias keys
-    okhi_ref[...] = jnp.where(ok, khi[safe], 0)
-    ovlo_ref[...] = jnp.where(ok, vlo[safe], 0)
-    ovhi_ref[...] = jnp.where(ok, vhi[safe], 0)
+        return (jnp.where(act & less, mid + 1, lo),
+                jnp.where(act & ~less, mid, hi))
+
+    zero = jnp.zeros_like(qhi)
+    lo, _ = jax.lax.fori_loop(0, steps, body, (zero, zero + n))
+    return lo
 
 
-@functools.partial(jax.jit, static_argnames=("steps", "max_count",
-                                             "query_block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("steps", "max_count"))
 def scan_window(qlo, qhi, counts, klo, khi, vlo, vhi, n, *, steps: int,
-                max_count: int, query_block: int = QUERY_BLOCK,
-                interpret: bool = True):
+                max_count: int):
     """qlo/qhi: [Q] int32 query-key halves (lo pre-biased); counts: [Q]
     int32 requested window widths; klo/khi/vlo/vhi: [N] int32 halves of
-    the sorted run (klo pre-biased); n: [1, 1] int32 live-entry count;
-    steps: host-computed ceil(log2(n+1)).  Returns (valid [Q, C] bool,
-    key_lo, key_hi, val_lo, val_hi [Q, C] int32) — rows are prefix
-    masks, keys come back un-biased."""
-    Q = qlo.shape[0]
-    N = klo.shape[0]
-    C = max_count
-    qb = min(query_block, Q)
-    assert Q % qb == 0, (Q, qb)
-    grid = (Q // qb,)
-    qtile = lambda w: pl.BlockSpec((qb, w), lambda i: (i, 0))
-    bcast = lambda r: pl.BlockSpec((r, 1), lambda i: (0, 0))
-    col = lambda a: a.reshape(-1, 1)
-    kern = functools.partial(_scan_kernel, steps=steps, max_count=C)
-    valid, oklo, okhi, ovlo, ovhi = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[qtile(1), qtile(1), qtile(1),
-                  bcast(N), bcast(N), bcast(N), bcast(N), bcast(1)],
-        out_specs=[qtile(C), qtile(C), qtile(C), qtile(C), qtile(C)],
-        out_shape=[
-            jax.ShapeDtypeStruct((Q, C), jnp.bool_),
-            jax.ShapeDtypeStruct((Q, C), jnp.int32),
-            jax.ShapeDtypeStruct((Q, C), jnp.int32),
-            jax.ShapeDtypeStruct((Q, C), jnp.int32),
-            jax.ShapeDtypeStruct((Q, C), jnp.int32),
-        ],
-        interpret=interpret,
-    )(col(qlo), col(qhi), col(counts), col(klo), col(khi), col(vlo),
-      col(vhi), n)
-    return valid, oklo, okhi, ovlo, ovhi
+    the sorted run (klo pre-biased); n: int32 live-entry count; steps:
+    host-computed ceil(log2(n+1)).  Returns (valid [Q, C] bool, key_lo,
+    key_hi, val_lo, val_hi [Q, C] int32) — rows are prefix masks, keys
+    come back un-biased."""
+    lo = lower_bound(khi, klo, n, qhi, qlo, steps=steps)
+    off = jax.lax.broadcasted_iota(jnp.int32, (qlo.shape[0], max_count), 1)
+    pos = lo[:, None] + off
+    ok = (off < counts[:, None]) & (pos < n)
+    safe = jnp.clip(pos, 0, klo.shape[0] - 1)
+    return (ok,
+            jnp.where(ok, klo[safe] ^ _BIAS, 0),  # un-bias keys
+            jnp.where(ok, khi[safe], 0),
+            jnp.where(ok, vlo[safe], 0),
+            jnp.where(ok, vhi[safe], 0))

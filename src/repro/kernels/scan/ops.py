@@ -1,8 +1,9 @@
-"""Host wrapper: sorted (keys, vals) export -> scan_window kernel calls.
+"""Host wrapper: sorted (keys, vals) export -> scan_window calls.
 
 Splits the 64-bit sorted run into int32 halves (low halves XOR-biased
 so signed lane compares realize unsigned 64-bit order), pads query
-batches to whole kernel blocks, and re-assembles per-query result rows.
+batches to a small family of shapes, and re-assembles per-query result
+rows.
 The prepared device form is memoized on the ``IndexSnapshot`` under the
 ``"scan"`` cache key, so steady-state batches pay gather + kernel only.
 """
@@ -17,7 +18,7 @@ import numpy as np
 from ...obs import RECORDER as _OBS
 from ..probe import combine64, split64
 from ..probe.fingerprint import account, fp64
-from .kernel import QUERY_BLOCK, scan_window
+from .kernel import scan_window
 
 # window widths are rounded up to whole lane rows so the family of
 # traced shapes stays small (YCSB-E counts are 1..100 -> always 128)
@@ -27,8 +28,9 @@ SCAN_LANES = 128
 # next-power-of-two family the lookup kernels use): scan batches are
 # few-and-heavy, so one fixed row count per (run-shape, window) keeps
 # the jit cache at a single entry while the padded-lane overhead stays
-# far below one window gather
+# far below one window gather; above QUERY_BLOCK, whole blocks
 QUERY_ROWS = 512
+QUERY_BLOCK = 4096
 
 _BIAS = np.int32(-(1 << 31))
 _EMPTY = ("scan-empty",)  # cache sentinel for an empty structure
@@ -38,7 +40,7 @@ def prepare_sorted(keys: np.ndarray, vals: np.ndarray) -> tuple:
     """Device-ready form of a sorted run: biased/split halves + the
     live count and lower-bound step budget.
 
-    The run is zero-padded to a power of two so the traced kernel
+    The run is zero-padded to a power of two so the traced
     shapes survive epoch changes (a write-heavy phase re-exports with
     a slightly different N every batch; without padding each would
     retrace).  The search interval is bounded by the live count and
@@ -58,19 +60,17 @@ def prepare_sorted(keys: np.ndarray, vals: np.ndarray) -> tuple:
     steps = max(1, n_pad.bit_length())
     return (jnp.asarray(klo ^ _BIAS), jnp.asarray(khi),
             jnp.asarray(vlo), jnp.asarray(vhi),
-            jnp.asarray([[n]], jnp.int32), n, steps)
+            jnp.asarray(n, jnp.int32), n, steps)
 
 
 def _run_kernel(queries: np.ndarray, counts: np.ndarray, prepared: tuple,
-                *, interpret: bool, lane_round: int = SCAN_LANES):
+                *, lane_round: int = SCAN_LANES):
     klo, khi, vlo, vhi, n_dev, n, steps = prepared
     q = np.asarray(queries, np.int64)
     c = np.asarray(counts, np.int32)
     Q = q.shape[0]
     C = max(1, int(c.max()) if c.size else 1)
     C = -(-C // lane_round) * lane_round
-    # whole QUERY_ROWS below one kernel block, whole blocks above it —
-    # the padded count must divide evenly into grid steps
     pad = (-Q) % (QUERY_BLOCK if Q > QUERY_BLOCK else QUERY_ROWS)
     with _OBS.span("kernel.scan", batch=Q, padded=Q + pad,
                    pad_ratio=pad / max(Q + pad, 1), window=C):
@@ -79,11 +79,9 @@ def _run_kernel(queries: np.ndarray, counts: np.ndarray, prepared: tuple,
             q = np.pad(q, (0, pad))
             c = np.pad(c, (0, pad))
         qlo, qhi = split64(q)
-        qb = min(QUERY_BLOCK, q.shape[0])
         valid, oklo, okhi, ovlo, ovhi = scan_window(
             jnp.asarray(qlo ^ _BIAS), jnp.asarray(qhi), jnp.asarray(c),
-            klo, khi, vlo, vhi, n_dev,
-            steps=steps, max_count=C, query_block=qb, interpret=interpret)
+            klo, khi, vlo, vhi, n_dev, steps=steps, max_count=C)
         valid = np.asarray(valid)[:Q]
         okeys = combine64(np.asarray(oklo)[:Q], np.asarray(okhi)[:Q])
         ovals = combine64(np.asarray(ovlo)[:Q], np.asarray(ovhi)[:Q])
@@ -91,8 +89,8 @@ def _run_kernel(queries: np.ndarray, counts: np.ndarray, prepared: tuple,
 
 
 def sorted_lookup(queries: np.ndarray, prepared: tuple, *,
-                  fingerprints: bool = True, stats: Optional[dict] = None,
-                  interpret: bool = True) -> Tuple[np.ndarray, np.ndarray]:
+                  fingerprints: bool = True, stats: Optional[dict] = None
+                  ) -> Tuple[np.ndarray, np.ndarray]:
     """Point lookups over a prepared sorted run: lower bound + window of
     1 + key-equality check.  Returns (found [Q] bool, values [Q] int64),
     bit-identical to a scalar binary search.
@@ -106,8 +104,7 @@ def sorted_lookup(queries: np.ndarray, prepared: tuple, *,
     # lane_round=1: a lookup needs a window of exactly one entry — no
     # point gathering a full 128-lane scan row per query
     valid, okeys, ovals = _run_kernel(q, np.ones(q.shape[0], np.int32),
-                                      prepared, interpret=interpret,
-                                      lane_round=1)
+                                      prepared, lane_round=1)
     live = valid[:, 0]
     found = live & (okeys[:, 0] == q)
     lanes = int(live.sum())
@@ -125,12 +122,11 @@ def sorted_lookup(queries: np.ndarray, prepared: tuple, *,
     return found, np.where(found, ovals[:, 0], 0)
 
 
-def sorted_scan(starts: np.ndarray, counts: np.ndarray, prepared: tuple, *,
-                interpret: bool = True) -> List[List[Tuple[int, int]]]:
+def sorted_scan(starts: np.ndarray, counts: np.ndarray, prepared: tuple
+                ) -> List[List[Tuple[int, int]]]:
     """Range scans over a prepared sorted run: per query, the first
     ``counts[i]`` entries with key >= starts[i] in ascending order."""
-    valid, okeys, ovals = _run_kernel(starts, counts, prepared,
-                                      interpret=interpret)
+    valid, okeys, ovals = _run_kernel(starts, counts, prepared)
     out: List[List[Tuple[int, int]]] = []
     for row_ok, row_k, row_v in zip(valid, okeys, ovals):
         m = int(row_ok.sum())  # prefix mask: first m lanes are live
@@ -151,7 +147,7 @@ def _prepared_from(snap, exporter: Exporter):
 
 
 def snapshot_lookup(snap, queries: np.ndarray, *, fingerprints: bool = True,
-                    stats: Optional[dict] = None, interpret: bool = True
+                    stats: Optional[dict] = None
                     ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
     """Batched lookup against an ``IndexSnapshot`` whose ``arrays`` is
     the sorted {"keys", "vals"} export (P-Masstree / P-BwTree /
@@ -163,11 +159,11 @@ def snapshot_lookup(snap, queries: np.ndarray, *, fingerprints: bool = True,
     if prepared is None:
         return None
     return sorted_lookup(queries, prepared, fingerprints=fingerprints,
-                         stats=stats, interpret=interpret)
+                         stats=stats)
 
 
 def snapshot_scan(snap, starts: Sequence[int], counts: Sequence[int],
-                  exporter: Exporter, *, interpret: bool = True
+                  exporter: Exporter
                   ) -> Optional[List[List[Tuple[int, int]]]]:
     """Batched range scans against an ``IndexSnapshot``; ``exporter``
     supplies the sorted run on first use (None for an empty structure)
@@ -176,5 +172,4 @@ def snapshot_scan(snap, starts: Sequence[int], counts: Sequence[int],
     if prepared is None:
         return None
     return sorted_scan(np.asarray(starts, np.int64),
-                       np.asarray(counts, np.int64), prepared,
-                       interpret=interpret)
+                       np.asarray(counts, np.int64), prepared)

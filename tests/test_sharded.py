@@ -7,6 +7,7 @@ replays exactly the crashed shard's sub-plan), the mesh read fan-out
 must match the per-shard path, and the per-shard span attribution must
 sum exactly to the aggregate ``ShardedPMem`` counters."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -233,8 +234,18 @@ def test_mesh_read_path_matches_per_shard(name, factory, scheme):
                             rng.integers(1, 1 << 60, 100)])  # mostly hits
     gets = Plan.from_ops([("lookup", int(k), 0) for k in probe])
     r_ps = idx.execute(gets, mesh=False)
-    r_mesh = idx.execute(gets, mesh=True)
+    obs.reset()
+    obs.enable()
+    try:
+        r_mesh = idx.execute(gets, mesh=True)
+    finally:
+        obs.disable()
     assert r_mesh.mesh and not r_ps.mesh
+    # fewer devices than shards: the fused probe runs on one device,
+    # and the span says so
+    [sp] = obs.spans("shard.mesh_lookup")
+    assert sp.attrs["placement"] == ("devices" if len(jax.devices()) >= 4
+                                     else "one_device")
     assert r_mesh.results == r_ps.results
     assert r_mesh.found == r_ps.found
     assert idx.stats["mesh_plans"] == 1

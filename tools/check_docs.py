@@ -5,8 +5,8 @@ Fails (exit 1) when:
 
 * a relative markdown link in README.md, docs/, EXPERIMENTS.md, or a
   kernel package README resolves to a missing file;
-* a ``kernels/<name>`` reference in the checked documents names a
-  kernel package that does not exist under src/repro/kernels/
+* a ``kernels/<name>`` reference in the checked documents names
+  neither a kernel package nor a module under src/repro/kernels/
   (dangling kernel-package references);
 * one of the index/plan kernel packages (probe, clht_probe,
   art_probe, scan, partition, conflict) is missing its README.md;
@@ -102,7 +102,8 @@ def check_file(path: pathlib.Path, kernel_pkgs: set) -> list:
         if not (path.parent / target).resolve().exists():
             errors.append(f"{rel}: dangling link -> {m.group(1)}")
     for m in KERNEL_REF_RE.finditer(text):
-        if m.group(1) not in kernel_pkgs:
+        if (m.group(1) not in kernel_pkgs
+                and not (KERNELS / f"{m.group(1)}.py").exists()):
             errors.append(f"{rel}: dangling kernel-package reference -> "
                           f"kernels/{m.group(1)}")
     return errors
