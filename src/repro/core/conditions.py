@@ -22,6 +22,7 @@ from typing import (Any, Callable, Dict, Iterator, List, Optional, Sequence,
 
 import numpy as np
 
+from ..obs import RECORDER as _OBS
 from .pmem import PMem, Region, CrashPoint
 
 # the probe-traffic counters every RecipeIndex carries (and every
@@ -29,10 +30,24 @@ from .pmem import PMem, Region, CrashPoint
 # candidates == fp_hits + fp_false_positives — is enforced at the
 # accounting site (kernels.probe.fingerprint.account); the merge sites
 # (plan deltas, sharded sub-results, metrics registries) sum these
-# exactly, so it holds at every aggregation level.
+# exactly, so it holds at every aggregation level.  The read path's
+# own tallies ride along: ``exports`` (snapshot exports built),
+# ``upload_bytes`` (host->device bytes: prepared exports and query
+# batches) and ``scalar_reads`` (keys answered by a per-key lookup).
 PROBE_STAT_KEYS = ("fp_compares", "candidates", "fp_hits",
                    "fp_false_positives", "pm_load_words",
-                   "optimistic_probes", "optimistic_retries")
+                   "optimistic_probes", "optimistic_retries",
+                   "exports", "upload_bytes", "scalar_reads")
+
+
+def _export_rows(arrays: Any) -> int:
+    """Rows of an export: P-CLHT buckets (chained rows included), the
+    entries of a sorted run, or radix node pages; 0 when empty."""
+    if arrays is None:
+        return 0
+    first = (arrays[0] if isinstance(arrays, tuple)
+             else next(iter(arrays.values())))
+    return int(np.shape(first)[0])
 
 
 def tracks_epoch(method):
@@ -267,7 +282,12 @@ class RecipeIndex:
         right validity tag), and ``publish_export`` installs the result
         only if the index hasn't moved since."""
         key = self._epoch_key()
-        return IndexSnapshot(epoch=key, arrays=self.export_arrays(),
+        with _OBS.span("snapshot.export", index=self.spec.name) as sp:
+            arrays = self.export_arrays()
+            if sp:
+                sp.set(entries=_export_rows(arrays))
+        self.probe_stats["exports"] += 1
+        return IndexSnapshot(epoch=key, arrays=arrays,
                              shard_epochs=self._effective_shard_epochs())
 
     def publish_export(self, snap: IndexSnapshot) -> bool:
@@ -545,17 +565,22 @@ class RecipeIndex:
                 return refined
         floor = self._rebuild_floor() if stale else self._MIN_KERNEL_BATCH
         if len(keys) < floor and not force_kernel:
-            return [self.lookup(int(k)) for k in keys]
+            return self._scalar_lookups(keys)
         try:
             res = self._kernel_lookup(self.snapshot(),
                                       np.asarray(keys, np.int64))
         except NotImplementedError:  # no array export for this index
-            return [self.lookup(int(k)) for k in keys]
+            return self._scalar_lookups(keys)
         if res is None:  # empty structure: nothing can be found
             return [None] * len(keys)
         found, vals = res
         return [v if f else None
                 for f, v in zip(found.tolist(), vals.tolist())]
+
+    def _scalar_lookups(self, keys: Sequence[int]) -> List[Optional[int]]:
+        """The per-key fallback, counted in ``scalar_reads``."""
+        self.probe_stats["scalar_reads"] += len(keys)
+        return [self.lookup(int(k)) for k in keys]
 
     def _refined_lookup(self, keys: np.ndarray) -> Optional[List[Optional[int]]]:
         """Serve a stale-snapshot batch by shard validity: queries in
@@ -583,8 +608,9 @@ class RecipeIndex:
             for i, f, v in zip(clean_idx.tolist(), found.tolist(),
                                vals.tolist()):
                 out[i] = v if f else None
-        for i in np.nonzero(~mask)[0].tolist():
-            out[i] = self.lookup(int(keys[i]))
+        dirty = np.nonzero(~mask)[0]
+        for i, v in zip(dirty.tolist(), self._scalar_lookups(keys[dirty])):
+            out[i] = v
         self.shard_stats["refined_batches"] += 1
         self.shard_stats["refined_queries"] += len(clean_idx)
         return out
@@ -635,7 +661,8 @@ class RecipeIndex:
             raise NotImplementedError(f"{self.spec.name} is unordered")
         from ..kernels.scan import snapshot_scan
         return snapshot_scan(snapshot, starts, counts,
-                             lambda: self._scan_export(snapshot))
+                             lambda: self._scan_export(snapshot),
+                             stats=self.probe_stats)
 
     def _scan_batch(self, start_keys: Sequence[int],
                     counts: Sequence[int], *, force_kernel: bool = False

@@ -66,6 +66,13 @@ _KIND_TO_WRITE_NAME = {PUT: "insert", UPDATE: "update", DELETE: "delete"}
 _WRITE_NAME_TO_KIND = {"insert": PUT, "update": UPDATE, "delete": DELETE,
                        "lookup": GET, "scan": SCAN}
 _WRITE_CODES = (PUT, UPDATE, DELETE)
+# the key domain: every index reserves word 0 as its empty-slot
+# sentinel, so no op may name key 0 (a scan may start there)
+NULL_KEY = 0
+_NULL_KEY_MSG = "key 0 is the indexes' empty-slot word; keys start at 1"
+# probe_stats keys whose per-wave deltas ride on the plan.wave span
+_WAVE_TALLIES = ("optimistic_retries", "exports", "upload_bytes",
+                 "scalar_reads")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,6 +111,8 @@ class Plan:
 
     # -- builders ---------------------------------------------------------
     def _append(self, kind: int, key: int, aux: int) -> int:
+        if key == NULL_KEY and kind != SCAN:
+            raise ValueError(_NULL_KEY_MSG)
         if self._arrays is not None and not self._kinds:
             # appending to a from_arrays plan: materialize the backing
             # lists first so the array-built ops are kept
@@ -159,6 +168,8 @@ class Plan:
         keys = np.asarray(keys, np.int64)
         aux = np.asarray(aux, np.int64)
         assert kinds.shape == keys.shape == aux.shape
+        if bool(((keys == NULL_KEY) & (kinds != SCAN)).any()):
+            raise ValueError(_NULL_KEY_MSG)
         plan = cls()
         plan._arrays = (kinds, keys, aux)
         return plan
@@ -528,8 +539,7 @@ def run_plan(index, plan: Plan, *, force_kernel: bool = False,
                                - p0["pm_load_words"],
                                fp_candidates=ps["candidates"]
                                - p0["candidates"],
-                               optimistic_retries=ps["optimistic_retries"]
-                               - p0["optimistic_retries"])
+                               **{k: ps[k] - p0[k] for k in _WAVE_TALLIES})
             if collect_results:
                 for i, r in zip(idx.tolist(), out):
                     results[i] = r

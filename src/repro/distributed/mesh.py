@@ -34,6 +34,8 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs import RECORDER as _OBS
+
 _BIAS = np.int32(-(1 << 31))
 
 @dataclasses.dataclass
@@ -50,11 +52,35 @@ class StackedRuns:
     n_shards: int
 
 
-def build_stacked(runs: Sequence[Optional[Tuple[np.ndarray, np.ndarray]]]
-                  ) -> StackedRuns:
+def _book_rows(stats: Optional[Sequence[dict]], row_bytes: int) -> None:
+    """Book one shard row of ``row_bytes`` uploaded into each shard's
+    ``upload_bytes``, so the per-shard counters sum to the upload."""
+    for st in stats or ():
+        st["upload_bytes"] += row_bytes
+
+
+def build_stacked(runs: Sequence[Optional[Tuple[np.ndarray, np.ndarray]]],
+                  stats: Optional[Sequence[dict]] = None) -> StackedRuns:
     """Stack per-shard sorted (keys, vals) runs (None = empty shard)
     into one [S, N] device form, N padded to a common power of two.
-    With a device per shard, row s lives on device s."""
+    With a device per shard, row s lives on device s.  Runs in a
+    ``snapshot.upload`` span; ``stats`` (one probe_stats dict per
+    shard) takes each shard's row of the upload."""
+    with _OBS.span("snapshot.upload", kernel="mesh_lookup") as sp:
+        stacked = _stack(runs)
+        arrays = [stacked.khi, stacked.klo, stacked.vhi, stacked.vlo,
+                  stacked.n]
+        nbytes = sum(int(a.nbytes) for a in arrays)
+        _book_rows(stats, nbytes // stacked.n_shards)
+        if sp:
+            import jax
+            jax.block_until_ready(arrays)
+            sp.set(bytes=nbytes)
+    return stacked
+
+
+def _stack(runs: Sequence[Optional[Tuple[np.ndarray, np.ndarray]]]
+           ) -> StackedRuns:
     from ..kernels.probe import split64
     import jax
     import jax.numpy as jnp
@@ -131,12 +157,15 @@ def placement(n_shards: int) -> str:
 
 
 def mesh_lookup(stacked: StackedRuns,
-                queries: Sequence[np.ndarray]
+                queries: Sequence[np.ndarray],
+                stats: Optional[Sequence[dict]] = None
                 ) -> List[Tuple[np.ndarray, np.ndarray]]:
     """Probe all shards in one dispatch.  ``queries[s]`` is shard s's
     (possibly empty) int64 query vector; returns per-shard
     (found [Qs] bool, values [Qs] int64), bit-identical to probing each
-    shard's sorted run with ``kernels.scan.sorted_lookup``."""
+    shard's sorted run with ``kernels.scan.sorted_lookup``.  ``stats``
+    (one probe_stats dict per shard) takes each shard's query row of
+    the upload."""
     from ..kernels.probe import combine64, split64
     import jax.numpy as jnp
     S = stacked.n_shards
@@ -154,9 +183,10 @@ def mesh_lookup(stacked: StackedRuns,
             qlo[s, :q_len[s]] = lo
     fn = compiled_probe(stacked.steps, shard_mesh(S)
                         if placement(S) == "devices" else None)
+    qhi, qlo = jnp.asarray(qhi), jnp.asarray(qlo ^ _BIAS)
+    _book_rows(stats, (int(qhi.nbytes) + int(qlo.nbytes)) // S)
     found, vhi, vlo = fn(stacked.khi, stacked.klo, stacked.vhi, stacked.vlo,
-                         stacked.n, jnp.asarray(qhi),
-                         jnp.asarray(qlo ^ _BIAS))
+                         stacked.n, qhi, qlo)
     found = np.asarray(found)
     vals = combine64(np.asarray(vlo), np.asarray(vhi))
     return [(found[s, :q_len[s]], vals[s, :q_len[s]]) for s in range(S)]

@@ -297,6 +297,8 @@ class ShardedIndex:
     def _execute_mesh(self, keys, parts, result,
                       collect_results: bool) -> None:
         from .mesh import build_stacked, mesh_lookup, placement
+        stats = [sh.probe_stats for sh in self.shards]
+        p0 = [dict(st) for st in stats]
         ek = tuple(sh._epoch_key() for sh in self.shards)
         if self._mesh_cache is None or self._mesh_cache[0] != ek:
             runs = []
@@ -309,13 +311,14 @@ class ShardedIndex:
                         sp.set(stores=d.stores, loads=d.loads, clwb=d.clwb,
                                fence=d.fence,
                                lines_touched=d.lines_touched)
-            self._mesh_cache = (ek, build_stacked(runs))
+            self._mesh_cache = (ek, build_stacked(runs, stats))
         stacked = self._mesh_cache[1]
         t0 = time.perf_counter_ns()
         with _OBS.span("shard.mesh_lookup", shards=self.n_shards,
                        ops=int(keys.shape[0]),
                        placement=placement(self.n_shards)):
-            per_shard = mesh_lookup(stacked, [keys[idx] for idx in parts])
+            per_shard = mesh_lookup(stacked, [keys[idx] for idx in parts],
+                                    stats)
         dt = time.perf_counter_ns() - t0
         # one fused dispatch covers all shards: book each shard's share
         # of the dispatch by its query weight (sums back to the wall)
@@ -327,6 +330,11 @@ class ShardedIndex:
         result.wave_widths.append(int(keys.shape[0]))
         result.mesh = True
         self.stats["mesh_plans"] += 1
+        # the shards' probe-traffic deltas (exports, upload bytes), as
+        # the per-shard path folds its sub-plans'
+        for st, st0 in zip(stats, p0):
+            for name in result.probe:
+                result.probe[name] += st[name] - st0[name]
         for (found, vals), idx in zip(per_shard, parts):
             result.found += int(found.sum())
             if collect_results:

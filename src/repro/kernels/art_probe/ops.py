@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...obs import RECORDER as _OBS
-from ..probe import combine64, pad_queries, split64
+from ..probe import book_upload, combine64, pad_queries, split64
 from ..probe.fingerprint import account, fp_partial
 from .kernel import art_descend
 from .ref import leaf_fp_lane
@@ -44,18 +44,22 @@ def key_bytes(keys: np.ndarray) -> np.ndarray:
     return key_units(keys, 8)
 
 
-def _prepare(arrays: Dict[str, np.ndarray]) -> tuple:
-    """Device-ready node pages: split leaf words, convert once."""
-    lklo, lkhi = split64(arrays["leaf_key"])
-    lvlo, lvhi = split64(arrays["leaf_val"])
-    lfp = leaf_fp_lane(arrays).astype(np.int32)
-    return (int(arrays.get("unit_bits", 8)),
-            jnp.asarray(arrays["children"]),
-            jnp.asarray(arrays["level"], jnp.int32),
-            jnp.asarray(arrays["is_leaf"], jnp.int32),
-            jnp.asarray(lfp),
-            jnp.asarray(lklo), jnp.asarray(lkhi),
-            jnp.asarray(lvlo), jnp.asarray(lvhi))
+def _prepare(arrays: Dict[str, np.ndarray],
+             stats: Optional[dict] = None) -> tuple:
+    """Device-ready node pages: split leaf words, convert once, in a
+    ``snapshot.upload`` span whose bytes are booked into ``stats``."""
+    with _OBS.span("snapshot.upload", kernel="art_probe") as sp:
+        lklo, lkhi = split64(arrays["leaf_key"])
+        lvlo, lvhi = split64(arrays["leaf_val"])
+        lfp = leaf_fp_lane(arrays).astype(np.int32)
+        pages = [jnp.asarray(arrays["children"]),
+                 jnp.asarray(arrays["level"], jnp.int32),
+                 jnp.asarray(arrays["is_leaf"], jnp.int32),
+                 jnp.asarray(lfp),
+                 jnp.asarray(lklo), jnp.asarray(lkhi),
+                 jnp.asarray(lvlo), jnp.asarray(lvhi)]
+        book_upload(stats, sp, pages, wait=True)
+    return (int(arrays.get("unit_bits", 8)), *pages)
 
 
 def _descend(queries: np.ndarray, pages: tuple, *,
@@ -66,23 +70,26 @@ def _descend(queries: np.ndarray, pages: tuple, *,
     Q = q.shape[0]
     pad = pad_queries(Q)
     with _OBS.span("kernel.art_probe", batch=Q, padded=Q + pad,
-                   pad_ratio=pad / max(Q + pad, 1), unit_bits=unit_bits,
-                   fingerprints=fingerprints) as sp:
-        if pad:
-            q = np.pad(q, (0, pad))  # padded lanes miss at the leaf check
-        qlo, qhi = split64(q)
-        qfp = fp_partial(q).astype(np.int32)
-        found, olo, ohi, nenc, nfp, nfalse = art_descend(
-            jnp.asarray(key_units(q, unit_bits)), jnp.asarray(qlo),
-            jnp.asarray(qhi), jnp.asarray(qfp), *node_pages)
-        found = np.asarray(found)[:Q]
-        values = combine64(np.asarray(olo)[:Q], np.asarray(ohi)[:Q])
+                   unit_bits=unit_bits, fingerprints=fingerprints) as sp:
+        with _OBS.span("kernel.launch") as lsp:
+            if pad:
+                q = np.pad(q, (0, pad))  # padded lanes miss at the leaf
+            qlo, qhi = split64(q)
+            qfp = fp_partial(q).astype(np.int32)
+            args = [jnp.asarray(a)
+                    for a in (key_units(q, unit_bits), qlo, qhi, qfp)]
+            book_upload(stats, lsp, args)
+            out = art_descend(*args, *node_pages)
+        with _OBS.span("kernel.fetch", arrays=len(out)):
+            found, olo, ohi, nenc, nfp, nfalse = (np.asarray(o)[:Q]
+                                                  for o in out)
+        values = combine64(olo, ohi)
         # lanes = leaves actually reached (the radix descent has no
         # fixed window; internal hops are index words, not key lanes)
-        lanes = int(np.asarray(nenc)[:Q].sum())
+        lanes = int(nenc.sum())
         if fingerprints:
-            cand = int(np.asarray(nfp)[:Q].sum())
-            false = int(np.asarray(nfalse)[:Q].sum())
+            cand = int(nfp.sum())
+            false = int(nfalse.sum())
             account(stats, lanes=lanes, fp_candidates=cand,
                     fp_hits=cand - false, fp_false=false, fingerprints=True)
             if sp:
@@ -99,8 +106,8 @@ def batched_lookup(queries: np.ndarray, arrays: Dict[str, np.ndarray], *,
     """queries: [Q] int64; arrays: PART/PHOT export_arrays output.
     Returns (found [Q] bool, values [Q] int64), bit-identical to the
     scalar ``lookup`` against the same snapshot."""
-    return _descend(queries, _prepare(arrays), fingerprints=fingerprints,
-                    stats=stats)
+    return _descend(queries, _prepare(arrays, stats),
+                    fingerprints=fingerprints, stats=stats)
 
 
 def snapshot_lookup(snap, queries: np.ndarray, *, fingerprints: bool = True,
@@ -110,6 +117,6 @@ def snapshot_lookup(snap, queries: np.ndarray, *, fingerprints: bool = True,
     pages; the split + device conversion is memoized on the snapshot."""
     pages = snap.cache.get("art_probe")
     if pages is None:
-        pages = _prepare(snap.arrays)
+        pages = _prepare(snap.arrays, stats)
         snap.cache["art_probe"] = pages
     return _descend(queries, pages, fingerprints=fingerprints, stats=stats)

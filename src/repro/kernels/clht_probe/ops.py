@@ -22,7 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...obs import RECORDER as _OBS
-from ..probe import combine64, pad_queries, probe64_lookup, split64
+from ..probe import (book_upload, combine64, pad_queries, probe64_lookup,
+                     split64)
 from ..probe.fingerprint import account, fp64
 from ..probe.kernel import probe64, probe64_fp
 from .kernel import clht_probe
@@ -65,20 +66,41 @@ def _gather_probe(bucket, qlo, qhi, qfp, klo, khi, vlo, vhi, fps, nxt, *,
     straight to the probe64 kernel — nothing materializes on the host.
     With ``use_fp`` the fingerprint lane is windowed alongside and the
     fingerprint-compare pre-pass kernel runs instead."""
-    rows = []
-    cur = bucket
-    for _ in range(depth):
-        rows.append(cur)
-        cur = jnp.where(cur >= 0, nxt[jnp.maximum(cur, 0)], -1)
-    arrays = (klo, khi, vlo, vhi) + ((fps,) if use_fp else ())
-    windows = []
-    for arr in arrays:
-        parts = [jnp.where(r[:, None] >= 0, arr[jnp.maximum(r, 0)], 0)
-                 for r in rows]
-        windows.append(jnp.concatenate(parts, axis=1))
-    if use_fp:
-        return probe64_fp(qlo, qhi, qfp, *windows)
-    return probe64(qlo, qhi, *windows)
+    with jax.named_scope("chain_walk"):
+        rows = []
+        cur = bucket
+        for _ in range(depth):
+            rows.append(cur)
+            cur = jnp.where(cur >= 0, nxt[jnp.maximum(cur, 0)], -1)
+        arrays = (klo, khi, vlo, vhi) + ((fps,) if use_fp else ())
+        windows = []
+        for arr in arrays:
+            parts = [jnp.where(r[:, None] >= 0, arr[jnp.maximum(r, 0)], 0)
+                     for r in rows]
+            windows.append(jnp.concatenate(parts, axis=1))
+    with jax.named_scope("probe"):
+        if use_fp:
+            return probe64_fp(qlo, qhi, qfp, *windows)
+        return probe64(qlo, qhi, *windows)
+
+
+def _prepare(snap, stats: Optional[dict]) -> tuple:
+    """The per-epoch device form of a PCLHT export: int32 halves of the
+    slot words, the fingerprint lane, the chain pointers, and the
+    longest overflow chain."""
+    with _OBS.span("snapshot.upload", kernel="clht_probe") as sp:
+        keys, vals, nxt, n, fps = snap.arrays
+        nxt = np.asarray(nxt, np.int64)
+        depth, cur = 1, nxt[nxt >= 0]
+        while cur.size and depth < 64:  # longest chain in this epoch
+            depth += 1
+            hops = nxt[cur]
+            cur = hops[hops >= 0]
+        halves = [jnp.asarray(h) for kv in (keys, vals) for h in split64(kv)]
+        fps_dev = jnp.asarray(np.asarray(fps, np.int32))
+        nxt_dev = jnp.asarray(nxt.astype(np.int32))
+        book_upload(stats, sp, halves + [fps_dev, nxt_dev], wait=True)
+    return halves, fps_dev, nxt_dev, depth, int(n)
 
 
 def snapshot_lookup(snap, queries: np.ndarray, *, fingerprints: bool = True,
@@ -94,42 +116,33 @@ def snapshot_lookup(snap, queries: np.ndarray, *, fingerprints: bool = True,
     ``fingerprints`` is on, with filter counts folded into ``stats``."""
     prepared = snap.cache.get("clht_probe")
     if prepared is None:
-        keys, vals, nxt, n, fps = snap.arrays
-        nxt = np.asarray(nxt, np.int64)
-        depth, cur = 1, nxt[nxt >= 0]
-        while cur.size and depth < 64:  # longest chain in this epoch
-            depth += 1
-            hops = nxt[cur]
-            cur = hops[hops >= 0]
-        halves = [jnp.asarray(h) for kv in (keys, vals) for h in split64(kv)]
-        prepared = (halves, jnp.asarray(np.asarray(fps, np.int32)),
-                    jnp.asarray(nxt.astype(np.int32)), depth, int(n))
-        snap.cache["clht_probe"] = prepared
+        prepared = snap.cache["clht_probe"] = _prepare(snap, stats)
     halves, fps_dev, nxt_dev, depth, n = prepared
     q = np.asarray(queries, np.int64)
     Q = q.shape[0]
     W = depth * SLOTS
     pad = pad_queries(Q)
     with _OBS.span("kernel.clht_probe", batch=Q, padded=Q + pad,
-                   pad_ratio=pad / max(Q + pad, 1), depth=depth,
-                   fingerprints=fingerprints) as sp:
-        if pad:
-            # padded queries are 0 == the empty-slot sentinel; they probe
-            # bucket mix64(0) % n and the rows are sliced off below
-            q = np.pad(q, (0, pad))
-        bucket = (mix64(q) % _U64(n)).astype(np.int32)
-        qlo, qhi = split64(q)
-        qfp = fp64(q).astype(np.int32)
-        out = _gather_probe(
-            jnp.asarray(bucket), jnp.asarray(qlo), jnp.asarray(qhi),
-            jnp.asarray(qfp), *halves, fps_dev, nxt_dev, depth=depth,
-            use_fp=fingerprints)
-        found, olo, ohi = out[:3]
-        found = np.asarray(found)[:Q]
-        values = combine64(np.asarray(olo)[:Q], np.asarray(ohi)[:Q])
+                   depth=depth, fingerprints=fingerprints) as sp:
+        with _OBS.span("kernel.launch") as lsp:
+            if pad:
+                # padded queries are 0 == the empty-slot sentinel; they
+                # probe bucket mix64(0) % n and the rows are sliced off
+                q = np.pad(q, (0, pad))
+            bucket = (mix64(q) % _U64(n)).astype(np.int32)
+            qlo, qhi = split64(q)
+            qfp = fp64(q).astype(np.int32)
+            args = [jnp.asarray(a) for a in (bucket, qlo, qhi, qfp)]
+            book_upload(stats, lsp, args)
+            out = _gather_probe(*args, *halves, fps_dev, nxt_dev,
+                                depth=depth, use_fp=fingerprints)
+        with _OBS.span("kernel.fetch", arrays=len(out)):
+            out = [np.asarray(o)[:Q] for o in out]
+        found = out[0]
+        values = combine64(out[1], out[2])
         if fingerprints:
-            cand = int(np.asarray(out[3])[:Q].sum())
-            false = int(np.asarray(out[4])[:Q].sum())
+            cand = int(out[3].sum())
+            false = int(out[4].sum())
             account(stats, lanes=Q * W, fp_candidates=cand,
                     fp_hits=cand - false, fp_false=false, fingerprints=True)
             if sp:

@@ -11,12 +11,11 @@ cannot run inside default-precision jax anyway.
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
-
-from typing import Optional
 
 from ...obs import RECORDER as _OBS
 from .fingerprint import account, fp64
@@ -39,6 +38,22 @@ def combine64(lo: np.ndarray, hi: np.ndarray) -> np.ndarray:
     """(lo, hi) int32 halves -> int64 words."""
     u = (np.asarray(hi).astype(np.int64) & 0xFFFFFFFF) << 32
     return u | (np.asarray(lo).astype(np.int64) & 0xFFFFFFFF)
+
+
+def book_upload(stats: Optional[dict], sp, arrays: Sequence, *,
+                wait: bool = False) -> None:
+    """Count ``arrays`` (just handed to the device) as host->device
+    bytes: their ``nbytes`` go to ``stats["upload_bytes"]`` and to the
+    open span's ``bytes``.  With ``wait`` and tracing on, block until
+    the transfer lands, so the span holds the copy and not only its
+    dispatch; with tracing off nothing blocks."""
+    nbytes = sum(int(a.nbytes) for a in arrays)
+    if stats is not None:
+        stats["upload_bytes"] += nbytes
+    if sp:
+        if wait:
+            jax.block_until_ready(list(arrays))
+        sp.set(bytes=nbytes)
 
 
 def gather_chain_windows(start: np.ndarray, nxt: np.ndarray,
@@ -108,34 +123,34 @@ def probe64_windows(queries: np.ndarray, split_windows: Sequence[np.ndarray],
     W = int(klo.shape[1])
     use_fp = fingerprints and fp_window is not None
     pad = pad_queries(Q)
-    with _OBS.span("kernel.probe64", batch=Q, padded=Q + pad,
-                   pad_ratio=pad / max(Q + pad, 1),
-                   window=W, fingerprints=use_fp) as sp:
-        if pad:
-            # padded queries are 0 == the empty-slot sentinel, so they
-            # may "hit" padding slots — harmless, rows are sliced below
-            queries = np.pad(queries, (0, pad))
-            klo, khi, vlo, vhi = (np.pad(w, ((0, pad), (0, 0)))
-                                  for w in (klo, khi, vlo, vhi))
-        qlo, qhi = split64(queries)
-        if use_fp:
+    with _OBS.span("kernel.probe64", batch=Q, padded=Q + pad, window=W,
+                   fingerprints=use_fp) as sp:
+        with _OBS.span("kernel.launch") as lsp:
             if pad:
-                fp_window = np.pad(fp_window, ((0, pad), (0, 0)))
-            qfp = fp64(queries).astype(np.int32)
-            found, olo, ohi, nfp, nfalse = probe64_fp(
-                jnp.asarray(qlo), jnp.asarray(qhi), jnp.asarray(qfp),
-                jnp.asarray(klo), jnp.asarray(khi), jnp.asarray(vlo),
-                jnp.asarray(vhi), jnp.asarray(fp_window.astype(np.int32)))
-        else:
-            found, olo, ohi = probe64(
-                jnp.asarray(qlo), jnp.asarray(qhi), jnp.asarray(klo),
-                jnp.asarray(khi), jnp.asarray(vlo), jnp.asarray(vhi))
-        found = np.asarray(found)[:Q]
-        values = combine64(np.asarray(olo)[:Q], np.asarray(ohi)[:Q])
+                # padded queries are 0 == the empty-slot sentinel, so they
+                # may "hit" padding slots — harmless, rows are sliced below
+                queries = np.pad(queries, (0, pad))
+                klo, khi, vlo, vhi = (np.pad(w, ((0, pad), (0, 0)))
+                                      for w in (klo, khi, vlo, vhi))
+            qlo, qhi = split64(queries)
+            if use_fp:
+                if pad:
+                    fp_window = np.pad(fp_window, ((0, pad), (0, 0)))
+                host = [qlo, qhi, fp64(queries).astype(np.int32), klo, khi,
+                        vlo, vhi, fp_window.astype(np.int32)]
+            else:
+                host = [qlo, qhi, klo, khi, vlo, vhi]
+            args = [jnp.asarray(a) for a in host]
+            book_upload(stats, lsp, args)
+            out = (probe64_fp if use_fp else probe64)(*args)
+        with _OBS.span("kernel.fetch", arrays=len(out)):
+            out = [np.asarray(o)[:Q] for o in out]
+        found = out[0]
+        values = combine64(out[1], out[2])
         if use_fp:
             # counters over the real (un-padded) query rows only
-            cand = int(np.asarray(nfp)[:Q].sum())
-            false = int(np.asarray(nfalse)[:Q].sum())
+            cand = int(out[3].sum())
+            false = int(out[4].sum())
             account(stats, lanes=Q * W, fp_candidates=cand,
                     fp_hits=cand - false, fp_false=false, fingerprints=True)
             if sp:

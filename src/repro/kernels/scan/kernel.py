@@ -70,7 +70,8 @@ def scan_window(qlo, qhi, counts, klo, khi, vlo, vhi, n, *, steps: int,
     host-computed ceil(log2(n+1)).  Returns (valid [Q, C] bool, key_lo,
     key_hi, val_lo, val_hi [Q, C] int32) — rows are prefix masks, keys
     come back un-biased."""
-    lo = lower_bound(khi, klo, n, qhi, qlo, steps=steps)
+    with jax.named_scope("search"):
+        lo = lower_bound(khi, klo, n, qhi, qlo, steps=steps)
     off = jax.lax.broadcasted_iota(jnp.int32, (qlo.shape[0], max_count), 1)
     pos = lo[:, None] + off
     ok = (off < counts[:, None]) & (pos < n)

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...obs import RECORDER as _OBS
-from ..probe import combine64, split64
+from ..probe import book_upload, combine64, split64
 from ..probe.fingerprint import account, fp64
 from .kernel import scan_window
 
@@ -36,7 +36,8 @@ _BIAS = np.int32(-(1 << 31))
 _EMPTY = ("scan-empty",)  # cache sentinel for an empty structure
 
 
-def prepare_sorted(keys: np.ndarray, vals: np.ndarray) -> tuple:
+def prepare_sorted(keys: np.ndarray, vals: np.ndarray,
+                   stats: Optional[dict] = None) -> tuple:
     """Device-ready form of a sorted run: biased/split halves + the
     live count and lower-bound step budget.
 
@@ -45,26 +46,30 @@ def prepare_sorted(keys: np.ndarray, vals: np.ndarray) -> tuple:
     a slightly different N every batch; without padding each would
     retrace).  The search interval is bounded by the live count and
     the window gather masks ``pos < n``, so the padding is never
-    observed."""
-    k = np.asarray(keys, np.int64)
-    v = np.asarray(vals, np.int64)
-    n = int(k.shape[0])
-    n_pad = 128
-    while n_pad < n:
-        n_pad <<= 1
-    if n_pad > n:
-        k = np.pad(k, (0, n_pad - n))
-        v = np.pad(v, (0, n_pad - n))
-    klo, khi = split64(k)
-    vlo, vhi = split64(v)
-    steps = max(1, n_pad.bit_length())
-    return (jnp.asarray(klo ^ _BIAS), jnp.asarray(khi),
-            jnp.asarray(vlo), jnp.asarray(vhi),
-            jnp.asarray(n, jnp.int32), n, steps)
+    observed.  The whole preparation runs in a ``snapshot.upload``
+    span; its bytes are booked into ``stats``."""
+    with _OBS.span("snapshot.upload", kernel="scan") as sp:
+        k = np.asarray(keys, np.int64)
+        v = np.asarray(vals, np.int64)
+        n = int(k.shape[0])
+        n_pad = 128
+        while n_pad < n:
+            n_pad <<= 1
+        if n_pad > n:
+            k = np.pad(k, (0, n_pad - n))
+            v = np.pad(v, (0, n_pad - n))
+        klo, khi = split64(k)
+        vlo, vhi = split64(v)
+        steps = max(1, n_pad.bit_length())
+        dev = [jnp.asarray(klo ^ _BIAS), jnp.asarray(khi),
+               jnp.asarray(vlo), jnp.asarray(vhi), jnp.asarray(n, jnp.int32)]
+        book_upload(stats, sp, dev, wait=True)
+    return (*dev, n, steps)
 
 
 def _run_kernel(queries: np.ndarray, counts: np.ndarray, prepared: tuple,
-                *, lane_round: int = SCAN_LANES):
+                *, lane_round: int = SCAN_LANES,
+                stats: Optional[dict] = None):
     klo, khi, vlo, vhi, n_dev, n, steps = prepared
     q = np.asarray(queries, np.int64)
     c = np.asarray(counts, np.int32)
@@ -72,19 +77,23 @@ def _run_kernel(queries: np.ndarray, counts: np.ndarray, prepared: tuple,
     C = max(1, int(c.max()) if c.size else 1)
     C = -(-C // lane_round) * lane_round
     pad = (-Q) % (QUERY_BLOCK if Q > QUERY_BLOCK else QUERY_ROWS)
-    with _OBS.span("kernel.scan", batch=Q, padded=Q + pad,
-                   pad_ratio=pad / max(Q + pad, 1), window=C):
-        if pad:
-            # padded queries carry count 0, so their rows come back empty
-            q = np.pad(q, (0, pad))
-            c = np.pad(c, (0, pad))
-        qlo, qhi = split64(q)
-        valid, oklo, okhi, ovlo, ovhi = scan_window(
-            jnp.asarray(qlo ^ _BIAS), jnp.asarray(qhi), jnp.asarray(c),
-            klo, khi, vlo, vhi, n_dev, steps=steps, max_count=C)
-        valid = np.asarray(valid)[:Q]
-        okeys = combine64(np.asarray(oklo)[:Q], np.asarray(okhi)[:Q])
-        ovals = combine64(np.asarray(ovlo)[:Q], np.asarray(ovhi)[:Q])
+    with _OBS.span("kernel.scan", batch=Q, padded=Q + pad, window=C):
+        with _OBS.span("kernel.launch") as lsp:
+            if pad:
+                # padded queries carry count 0, so their rows come back
+                # empty
+                q = np.pad(q, (0, pad))
+                c = np.pad(c, (0, pad))
+            qlo, qhi = split64(q)
+            args = [jnp.asarray(qlo ^ _BIAS), jnp.asarray(qhi),
+                    jnp.asarray(c)]
+            book_upload(stats, lsp, args)
+            out = scan_window(*args, klo, khi, vlo, vhi, n_dev,
+                              steps=steps, max_count=C)
+        with _OBS.span("kernel.fetch", arrays=len(out)):
+            valid, oklo, okhi, ovlo, ovhi = (np.asarray(o)[:Q] for o in out)
+        okeys = combine64(oklo, okhi)
+        ovals = combine64(ovlo, ovhi)
     return valid, okeys, ovals
 
 
@@ -104,7 +113,7 @@ def sorted_lookup(queries: np.ndarray, prepared: tuple, *,
     # lane_round=1: a lookup needs a window of exactly one entry — no
     # point gathering a full 128-lane scan row per query
     valid, okeys, ovals = _run_kernel(q, np.ones(q.shape[0], np.int32),
-                                      prepared, lane_round=1)
+                                      prepared, lane_round=1, stats=stats)
     live = valid[:, 0]
     found = live & (okeys[:, 0] == q)
     lanes = int(live.sum())
@@ -122,11 +131,13 @@ def sorted_lookup(queries: np.ndarray, prepared: tuple, *,
     return found, np.where(found, ovals[:, 0], 0)
 
 
-def sorted_scan(starts: np.ndarray, counts: np.ndarray, prepared: tuple
+def sorted_scan(starts: np.ndarray, counts: np.ndarray, prepared: tuple,
+                stats: Optional[dict] = None
                 ) -> List[List[Tuple[int, int]]]:
     """Range scans over a prepared sorted run: per query, the first
     ``counts[i]`` entries with key >= starts[i] in ascending order."""
-    valid, okeys, ovals = _run_kernel(starts, counts, prepared)
+    valid, okeys, ovals = _run_kernel(starts, counts, prepared,
+                                      stats=stats)
     out: List[List[Tuple[int, int]]] = []
     for row_ok, row_k, row_v in zip(valid, okeys, ovals):
         m = int(row_ok.sum())  # prefix mask: first m lanes are live
@@ -137,11 +148,12 @@ def sorted_scan(starts: np.ndarray, counts: np.ndarray, prepared: tuple
 Exporter = Callable[[], Optional[Tuple[np.ndarray, np.ndarray]]]
 
 
-def _prepared_from(snap, exporter: Exporter):
+def _prepared_from(snap, exporter: Exporter, stats: Optional[dict]):
     prepared = snap.cache.get("scan")
     if prepared is None:
         arrays = exporter()
-        prepared = _EMPTY if arrays is None else prepare_sorted(*arrays)
+        prepared = (_EMPTY if arrays is None
+                    else prepare_sorted(*arrays, stats=stats))
         snap.cache["scan"] = prepared
     return None if prepared is _EMPTY else prepared
 
@@ -155,7 +167,7 @@ def snapshot_lookup(snap, queries: np.ndarray, *, fingerprints: bool = True,
     is memoized on the snapshot."""
     prepared = _prepared_from(
         snap, lambda: None if snap.arrays is None
-        else (snap.arrays["keys"], snap.arrays["vals"]))
+        else (snap.arrays["keys"], snap.arrays["vals"]), stats)
     if prepared is None:
         return None
     return sorted_lookup(queries, prepared, fingerprints=fingerprints,
@@ -163,13 +175,13 @@ def snapshot_lookup(snap, queries: np.ndarray, *, fingerprints: bool = True,
 
 
 def snapshot_scan(snap, starts: Sequence[int], counts: Sequence[int],
-                  exporter: Exporter
+                  exporter: Exporter, stats: Optional[dict] = None
                   ) -> Optional[List[List[Tuple[int, int]]]]:
     """Batched range scans against an ``IndexSnapshot``; ``exporter``
     supplies the sorted run on first use (None for an empty structure)
     and the prepared form is memoized on the snapshot."""
-    prepared = _prepared_from(snap, exporter)
+    prepared = _prepared_from(snap, exporter, stats)
     if prepared is None:
         return None
     return sorted_scan(np.asarray(starts, np.int64),
-                       np.asarray(counts, np.int64), prepared)
+                       np.asarray(counts, np.int64), prepared, stats)

@@ -481,3 +481,165 @@ def test_server_admit_queue_depth_gauge_exact():
     server.submit([1, 2, 3, 4], max_new=2)
     server.step(16)
     assert server.stats["admit_queue_depth"] == 4
+
+
+# ---------------------------------------------------------------------------
+# the read path: snapshot export and upload, kernel launch and fetch,
+# and the exports / upload_bytes / scalar_reads counters
+# ---------------------------------------------------------------------------
+def _loaded(kind, n=96):
+    from repro.api import open_index
+    s = open_index(kind)
+    s.execute(Plan.from_ops([("insert", k, k * 7) for k in range(1, n + 1)]))
+    return s
+
+
+def _children(parent):
+    return [s for s in obs.spans() if s.parent_id == parent.span_id]
+
+
+def _probe64_read():
+    """The host-gathered window path: probe64 over P-CLHT's export."""
+    from repro.kernels.clht_probe import batched_lookup
+    s = _loaded("clht")
+    keys, vals, nxt, n, fps = s.index.snapshot().arrays
+    obs.enable()
+    found, _ = batched_lookup(np.arange(1, 41, dtype=np.int64), keys, vals,
+                              nxt, n_buckets=n, fps=fps)
+    assert found.all()
+
+
+def _plan_read(kind):
+    def read():
+        s = _loaded(kind)
+        obs.enable()
+        res = s.execute(Plan.from_ops([("lookup", k, 0)
+                                       for k in range(1, 41)]),
+                        force_kernel=True)
+        assert res.results == [k * 7 for k in range(1, 41)]
+    return read
+
+
+@pytest.mark.parametrize("span,read", [
+    ("kernel.clht_probe", _plan_read("clht")),
+    ("kernel.scan", _plan_read("masstree")),
+    ("kernel.art_probe", _plan_read("art")),
+    ("kernel.probe64", _probe64_read),
+], ids=["clht_probe", "scan", "art_probe", "probe64"])
+def test_launch_and_fetch_nest_in_each_read_kernel_span(span, read):
+    read()
+    obs.disable()
+    outer = obs.spans(span)
+    assert len(outer) == 1
+    kids = {s.name: s for s in _children(outer[0])}
+    assert set(kids) == {"kernel.launch", "kernel.fetch"}
+    launch, fetch = kids["kernel.launch"], kids["kernel.fetch"]
+    assert launch.ts + launch.dur <= fetch.ts  # launch, then fetch
+    assert launch.attrs["bytes"] > 0 and fetch.attrs["arrays"] >= 3
+    assert set(outer[0].attrs) <= {"batch", "padded", "depth", "window",
+                                   "unit_bits", "fingerprints",
+                                   "fp_candidates", "fp_false_positives"}
+
+
+def test_exports_count_the_export_spans():
+    s = _loaded("clht", n=700)
+    e0 = s.stats["exports"]
+    obs.enable()
+    s.execute(Plan.from_ops([("update", k, k * 11) for k in range(1, 601)]))
+    res = s.execute(Plan.from_ops([("lookup", k, 0) for k in range(1, 601)]))
+    obs.disable()
+    spans = obs.spans("snapshot.export")
+    assert spans and s.stats["exports"] - e0 == len(spans)
+    assert res.probe["exports"] == len(spans)
+    assert all(sp.attrs["index"] == "P-CLHT" and sp.attrs["entries"] > 0
+               for sp in spans)
+    # each export was uploaded once, inside the read wave
+    assert len(obs.spans("snapshot.upload")) == len(spans)
+    assert sum(w.attrs["exports"] for w in obs.spans("plan.wave")) \
+        == len(spans)
+
+
+def test_upload_bytes_follow_the_export_shapes():
+    """64 B per P-CLHT bucket row (four int32 halves of 3 slots, a
+    3-lane int32 fingerprint row, an int32 chain pointer) and 16 B per
+    padded query (bucket, two halves, fingerprint)."""
+    from repro.kernels.probe import pad_queries
+    s = _loaded("clht")
+    q = 40
+    obs.enable()
+    res = s.execute(Plan.from_ops([("lookup", k, 0)
+                                   for k in range(1, q + 1)]),
+                    force_kernel=True)
+    obs.disable()
+    rows = s.index.snapshot().arrays[0].shape[0]
+    table, queries = 64 * rows, 16 * (q + pad_queries(q))
+    assert [sp.attrs["bytes"] for sp in obs.spans("snapshot.upload")] \
+        == [table]
+    assert [sp.attrs["bytes"] for sp in obs.spans("kernel.launch")] \
+        == [queries]
+    assert res.probe["upload_bytes"] == table + queries
+    assert s.stats["upload_bytes"] == table + queries
+    (wave,) = obs.spans("plan.wave")
+    assert wave.attrs["upload_bytes"] == table + queries
+
+
+def test_a_read_under_the_rebuild_floor_counts_scalar_reads():
+    s = _loaded("clht")
+    s.update(5, 55)  # a scalar write: every shard of the export is stale
+    width = 40  # under P-CLHT's rebuild floor of 512
+    before = s.stats["scalar_reads"]
+    obs.enable()
+    res = s.execute(Plan.from_ops([("lookup", k, 0)
+                                   for k in range(1, width + 1)]))
+    obs.disable()
+    assert res.results[4] == 55
+    assert s.stats["scalar_reads"] - before == width
+    assert res.probe["scalar_reads"] == width
+    (wave,) = obs.spans("plan.wave")
+    assert wave.attrs["kind"] == "read"
+    assert wave.attrs["scalar_reads"] == width
+    assert not obs.spans("snapshot.export")  # no re-export under the floor
+
+
+def _read_path_run():
+    s = _loaded("clht", n=700)
+    out = [s.execute(Plan.from_ops([("lookup", k, 0)
+                                    for k in range(1, 601)])).results]
+    s.execute(Plan.from_ops([("update", k, k * 3) for k in range(1, 601)]))
+    out.append(s.execute(Plan.from_ops([("lookup", k, 0)
+                                        for k in range(1, 41)])).results)
+    out.append(s.execute(Plan.from_ops([("lookup", k, 0)
+                                        for k in range(1, 601)])).results)
+    return out, dict(s.stats)
+
+
+def test_read_path_tracing_off_records_nothing_and_changes_nothing():
+    untraced, stats_off = _read_path_run()
+    assert obs.spans() == []
+    obs.enable()
+    traced, stats_on = _read_path_run()
+    obs.disable()
+    assert traced == untraced
+    assert stats_on == stats_off
+    names = {s.name for s in obs.spans()}
+    assert {"snapshot.export", "snapshot.upload", "kernel.launch",
+            "kernel.fetch"} <= names
+
+
+def test_mesh_read_path_books_its_uploads_to_the_shards():
+    from repro.api import open_index
+    from repro.core.conditions import PROBE_STAT_KEYS
+    s = open_index("masstree", shards=4, mesh_reads=True)
+    s.execute(Plan.from_ops([("insert", k, k * 7) for k in range(1, 201)]))
+    obs.enable()
+    res = s.execute(Plan.from_ops([("lookup", k, 0) for k in range(1, 201)]))
+    obs.disable()
+    assert res.mesh and res.results == [k * 7 for k in range(1, 201)]
+    per_shard = [sh.probe_stats for sh in s.index.shards]
+    for k in PROBE_STAT_KEYS:
+        assert s.stats[k] == sum(ps[k] for ps in per_shard), k
+    (up,) = [sp for sp in obs.spans("snapshot.upload")
+             if sp.attrs["kernel"] == "mesh_lookup"]
+    assert res.probe["exports"] == 4
+    # the stacked export and a query row per shard, two int32 halves
+    assert res.probe["upload_bytes"] > up.attrs["bytes"] > 0

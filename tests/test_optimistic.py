@@ -179,6 +179,8 @@ def test_plan_crash_sweep_covers_validation_window(name):
 # ----------------------------------------------------------------------
 def test_session_counters_mirror_plan_probe_deltas_exactly():
     s = warm(n=96)
+    # warm()'s direct export ran outside any plan: count from here
+    base = dict(s.index.probe_stats)
     deltas = {k: 0 for k in PROBE_STAT_KEYS}
     for step in range(3):
         plan = Plan.from_ops(
@@ -188,7 +190,8 @@ def test_session_counters_mirror_plan_probe_deltas_exactly():
         for k in PROBE_STAT_KEYS:
             deltas[k] += res.probe[k]
     for k in PROBE_STAT_KEYS:
-        assert s.stats[k] == deltas[k] == s.index.probe_stats[k], k
+        assert (s.stats[k] == deltas[k]
+                == s.index.probe_stats[k] - base[k]), k
     assert (s.stats["candidates"]
             == s.stats["fp_hits"] + s.stats["fp_false_positives"])
 

@@ -198,10 +198,11 @@ def test_error_propagates_and_pipeline_survives():
     idx = _clht()
     _load(idx, range(1, 9))
     with PlanPipeline(idx) as pipe:
-        bad = pipe.submit(Plan.from_ops([("lookup", 0, 0)]))  # CLHT: 0 is NULL
-        with pytest.raises(AssertionError):
+        # P-CLHT is unordered: executing a scan raises in the worker
+        bad = pipe.submit(Plan.from_ops([("scan", 1, 4)]))
+        with pytest.raises(NotImplementedError):
             bad.wait()
-        with pytest.raises(AssertionError):
+        with pytest.raises(NotImplementedError):
             pipe.drain()  # drain surfaces the same error
         # the worker is still alive and the pipeline still usable
         ok = pipe.submit(Plan.from_ops([("lookup", 1, 0)]))

@@ -354,3 +354,26 @@ def test_pipeline_generations_are_garbage_collected():
     del h
     gc.collect()
     assert wr() is None, "drained generation results were retained"
+
+
+@pytest.mark.parametrize("build", ["from_ops", "from_arrays", "builder",
+                                   "session"])
+def test_plans_reject_key_zero(build):
+    """Key 0 is every index's empty-slot word: a plan naming it is
+    refused where it is built, whatever the index; a scan may still
+    start there."""
+    from repro.api import open_index
+    with pytest.raises(ValueError, match="key 0"):
+        if build == "from_ops":
+            Plan.from_ops([("lookup", 1, 0), ("insert", 0, 5)])
+        elif build == "from_arrays":
+            Plan.from_arrays(np.array([GET, PUT], np.int32),
+                             np.array([1, 0], np.int64),
+                             np.array([0, 5], np.int64))
+        elif build == "builder":
+            Plan().delete(0)
+        else:
+            open_index("clht").get(0)
+    scan = Plan.from_arrays(np.array([SCAN], np.int32),
+                            np.array([0], np.int64), np.array([4], np.int64))
+    assert len(scan) == 1 and len(Plan.from_ops([("scan", 0, 4)])) == 1
