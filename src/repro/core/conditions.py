@@ -31,13 +31,16 @@ from .pmem import PMem, Region, CrashPoint
 # accounting site (kernels.probe.fingerprint.account); the merge sites
 # (plan deltas, sharded sub-results, metrics registries) sum these
 # exactly, so it holds at every aggregation level.  The read path's
-# own tallies ride along: ``exports`` (snapshot exports built),
-# ``upload_bytes`` (host->device bytes: prepared exports and query
-# batches) and ``scalar_reads`` (keys answered by a per-key lookup).
+# own tallies ride along: ``exports`` (snapshot exports built, deltas
+# included), ``upload_bytes`` (host->device bytes: prepared exports,
+# delta patches and query batches), ``scalar_reads`` (keys answered by
+# a per-key lookup), and ``delta_exports`` / ``delta_rows`` (exports
+# made by patching the stale snapshot's changed rows, and those rows).
 PROBE_STAT_KEYS = ("fp_compares", "candidates", "fp_hits",
                    "fp_false_positives", "pm_load_words",
                    "optimistic_probes", "optimistic_retries",
-                   "exports", "upload_bytes", "scalar_reads")
+                   "exports", "upload_bytes", "scalar_reads",
+                   "delta_exports", "delta_rows")
 
 
 def _export_rows(arrays: Any) -> int:
@@ -303,11 +306,24 @@ class RecipeIndex:
         return True
 
     def snapshot(self) -> IndexSnapshot:
-        """Return a point-in-time export, rebuilding only on epoch change."""
-        key = self._epoch_key()
-        if self._snapshot is None or self._snapshot.epoch != key:
-            self._snapshot = self.build_export()
+        """Return a point-in-time export, rebuilding only on epoch
+        change: a delta of the memoized stale snapshot where the index
+        can give one (``_delta_export``), else a full export."""
+        snap = self._snapshot
+        if snap is None or snap.epoch != self._epoch_key():
+            delta = None if snap is None else self._delta_export(snap)
+            self._snapshot = delta if delta is not None \
+                else self.build_export()
         return self._snapshot
+
+    def _delta_export(self, stale: IndexSnapshot) -> Optional[IndexSnapshot]:
+        """A snapshot at the current epoch made by patching ``stale``
+        with only what changed since it was taken, or None when the
+        index cannot give one.  ``stale`` must stay intact: the
+        optimistic and refined reads probe it after the patch.  The
+        base answers None, so an index re-exports whole unless it
+        overrides this (P-CLHT, whose export is one row per line)."""
+        return None
 
     # -- sharded batched write path (partition + group commit) ------------
     def shard_route(self, keys: np.ndarray) -> np.ndarray:

@@ -61,6 +61,7 @@ class PMSnapshot:
             r.pm[:] = pm
             r.dirty = set(dirty)
             r.pending = set(pending)
+            r.written = None  # no line record saw the rewrite above
             pmem.regions[rid] = r
         pmem._next_rid = self.next_rid
         pmem.alloc_log = list(self.alloc_log)

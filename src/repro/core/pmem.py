@@ -95,7 +95,7 @@ class Region:
     """A named PM allocation backed by two int64 arrays (cache + pm)."""
 
     __slots__ = ("name", "rid", "cache", "pm", "dirty", "pending", "n_words",
-                 "stores")
+                 "stores", "written", "written_limit")
 
     def __init__(self, name: str, rid: int, n_words: int):
         self.name = name
@@ -106,6 +106,10 @@ class Region:
         self.dirty: Set[int] = set()  # line indices dirty in cache
         self.pending: Set[int] = set()  # line indices clwb'd, awaiting fence
         self.stores = 0  # per-region store count (foreign-writer detection)
+        # lines stored to since ``PMem.track_lines`` armed the record
+        # (None: not tracked — the default, and past ``written_limit``)
+        self.written: Optional[Set[int]] = None
+        self.written_limit = 0
 
     def line_of(self, idx: int) -> int:
         return idx // WORDS_PER_LINE
@@ -157,6 +161,18 @@ class PMem:
     def free(self, region: Region) -> None:
         self.regions.pop(region.rid, None)
 
+    def track_lines(self, region: Region, limit: int) -> Set[int]:
+        """Arm a fresh record of the lines of ``region`` that stores
+        touch from now on (``store`` and ``store_bulk``), and return
+        it.  Re-arming replaces the record; one that would pass
+        ``limit`` lines is dropped (``region.written`` becomes None), so
+        a holder can tell a complete record by identity.  Only a region
+        that asks is tracked: the others pay one attribute check per
+        store."""
+        region.written = set()
+        region.written_limit = limit
+        return region.written
+
     def find(self, name: str) -> Optional[Region]:
         """Attach to an existing named region (process-restart path)."""
         for region in self.regions.values():
@@ -174,9 +190,15 @@ class PMem:
         if v >= _SIGN64:  # two's-complement wrap into the signed PM word
             v -= _M64 + 1
         region.cache[idx] = v
-        region.dirty.add(idx // WORDS_PER_LINE)
+        line = idx // WORDS_PER_LINE
+        region.dirty.add(line)
         region.stores += 1
         self.counters.stores += 1
+        written = region.written
+        if written is not None:
+            written.add(line)
+            if len(written) > region.written_limit:
+                region.written = None
 
     def store_bulk(self, region: Region, start: int,
                    words: np.ndarray) -> None:
@@ -191,6 +213,11 @@ class PMem:
         region.dirty.update(range(first, last + 1))
         region.stores += n
         self.counters.stores += n
+        written = region.written
+        if written is not None:
+            written.update(range(first, last + 1))
+            if len(written) > region.written_limit:
+                region.written = None
 
     def load_bulk(self, region: Region, start: int, n: int) -> np.ndarray:
         """Vectorized multi-word load (counts ``n`` loads and every line

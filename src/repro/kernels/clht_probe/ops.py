@@ -15,6 +15,7 @@ per key, collisions possible) for kernel benchmarking.
 from __future__ import annotations
 
 import functools
+from collections.abc import Sequence
 from typing import Optional, Tuple
 
 import jax
@@ -84,6 +85,16 @@ def _gather_probe(bucket, qlo, qhi, qfp, klo, khi, vlo, vhi, fps, nxt, *,
         return probe64(qlo, qhi, *windows)
 
 
+def _chain_depth(nxt: np.ndarray) -> int:
+    """The longest overflow chain, in rows, of an export's ``nxt``."""
+    depth, cur = 1, nxt[nxt >= 0]
+    while cur.size and depth < 64:
+        depth += 1
+        hops = nxt[cur]
+        cur = hops[hops >= 0]
+    return depth
+
+
 def _prepare(snap, stats: Optional[dict]) -> tuple:
     """The per-epoch device form of a PCLHT export: int32 halves of the
     slot words, the fingerprint lane, the chain pointers, and the
@@ -91,16 +102,97 @@ def _prepare(snap, stats: Optional[dict]) -> tuple:
     with _OBS.span("snapshot.upload", kernel="clht_probe") as sp:
         keys, vals, nxt, n, fps = snap.arrays
         nxt = np.asarray(nxt, np.int64)
-        depth, cur = 1, nxt[nxt >= 0]
-        while cur.size and depth < 64:  # longest chain in this epoch
-            depth += 1
-            hops = nxt[cur]
-            cur = hops[hops >= 0]
+        depth = _chain_depth(nxt)
         halves = [jnp.asarray(h) for kv in (keys, vals) for h in split64(kv)]
         fps_dev = jnp.asarray(np.asarray(fps, np.int32))
         nxt_dev = jnp.asarray(nxt.astype(np.int32))
         book_upload(stats, sp, halves + [fps_dev, nxt_dev], wait=True)
     return halves, fps_dev, nxt_dev, depth, int(n)
+
+
+# Rows of every scatter call (a block uploads 272 KiB).  One shape per
+# table, so the first delta compiles the only program a delta ever runs;
+# patch sizes bucketed by powers of two let a rarely seen bucket compile
+# long after the warm-up.  A longer patch runs as several blocks.
+PATCH_ROWS = 4096
+# columns of a packed patch row: row index, then 3 slots each of the
+# key and value halves and of the fingerprint lane, then the chain row
+_PATCH_COLS = 1 + 5 * SLOTS + 1
+
+
+@jax.jit
+def _scatter_rows(arrays, patch):
+    """Row scatter of one packed patch block into the device form.  The
+    buffers are not donated: the stale snapshot's form stays intact for
+    the reads that still probe it.  Padding rows index one past the
+    table and are dropped."""
+    rows = patch[:, 0]
+    cols = [patch[:, 1 + i * SLOTS:1 + (i + 1) * SLOTS] for i in range(5)]
+    cols.append(patch[:, 1 + 5 * SLOTS])
+    return tuple(a.at[rows].set(c, mode="drop")
+                 for a, c in zip(arrays, cols))
+
+
+def patch_prepared(prepared: tuple, rows: np.ndarray, keys: np.ndarray,
+                   vals: np.ndarray, nxt: np.ndarray, fps: np.ndarray, *,
+                   relinked: bool, stats: Optional[dict]) -> tuple:
+    """The device form of a delta export: ``prepared`` with bucket rows
+    ``rows`` replaced by (``keys``, ``vals``, ``nxt``, ``fps``), the
+    layout of ``PCLHT.export_arrays`` for those rows alone.  The rows
+    are packed into blocks of ``PATCH_ROWS``, uploaded, and scattered
+    one block per jitted call; the longest chain is measured again only
+    when ``relinked`` says a chain pointer may have changed."""
+    halves, fps_dev, nxt_dev, depth, n = prepared
+    k = int(rows.size)
+    with _OBS.span("snapshot.upload", kernel="clht_probe", delta=True,
+                   rows=k) as sp:
+        if not k:
+            book_upload(stats, sp, [])
+            return prepared
+        blocks = -(-k // PATCH_ROWS)
+        patch = np.zeros((blocks * PATCH_ROWS, _PATCH_COLS), np.int32)
+        patch[:, 0] = nxt_dev.shape[0]  # padding: out of range, dropped
+        patch[:k, 0] = rows
+        for i, h in enumerate((*split64(keys), *split64(vals), fps)):
+            patch[:k, 1 + i * SLOTS:1 + (i + 1) * SLOTS] = h
+        patch[:k, 1 + 5 * SLOTS] = nxt
+        patch_dev = [jnp.asarray(b) for b in np.split(patch, blocks)]
+        book_upload(stats, sp, patch_dev)
+        arrays = (*halves, fps_dev, nxt_dev)
+        for block in patch_dev:
+            arrays = _scatter_rows(arrays, block)
+        *halves, fps_dev, nxt_dev = arrays
+        if relinked:
+            depth = _chain_depth(np.asarray(nxt_dev).astype(np.int64))
+        if sp:
+            jax.block_until_ready(nxt_dev)
+    return halves, fps_dev, nxt_dev, depth, n
+
+
+class DeviceExport(Sequence):
+    """The host layout of ``PCLHT.export_arrays`` — (keys, vals, nxt,
+    n_buckets, fps) — of a delta export, read back from its device
+    form on first use.  A delta keeps no host copy of the table; the
+    few callers that want one get this exact view of the epoch."""
+
+    def __init__(self, prepared: tuple):
+        self._prepared = prepared
+        self._arrays: Optional[tuple] = None
+
+    def _host(self) -> tuple:
+        if self._arrays is None:
+            halves, fps_dev, nxt_dev, _, n = self._prepared
+            klo, khi, vlo, vhi = (np.asarray(h) for h in halves)
+            self._arrays = (combine64(klo, khi), combine64(vlo, vhi),
+                            np.asarray(nxt_dev).astype(np.int64), n,
+                            np.asarray(fps_dev).astype(np.uint8))
+        return self._arrays
+
+    def __getitem__(self, i):
+        return self._host()[i]
+
+    def __len__(self) -> int:
+        return 5
 
 
 def snapshot_lookup(snap, queries: np.ndarray, *, fingerprints: bool = True,

@@ -82,6 +82,22 @@ def test_clht_gather_probe_compiles(chip, depth, use_fp):
     assert "tpu_custom_call" in compiled.as_text()  # a kernel, compiled
 
 
+def test_clht_delta_scatter_compiles(chip):
+    """The delta export's row scatter into the device form of the
+    benchmark's P-CLHT table (2^21 buckets and a 2^20-row overflow
+    arena), one patch block: a copy of each array, since nothing is
+    donated, and the patch."""
+    rows = 3 << 20
+    table = spec((rows, 3), chip)
+    compiled = clht_ops._scatter_rows.lower(
+        (table,) * 5 + (spec((rows,), chip),),
+        spec((clht_ops.PATCH_ROWS, clht_ops._PATCH_COLS), chip)).compile()
+    mem = compiled.memory_analysis()
+    # whole copies out: the stale snapshot's arrays are left as they are
+    assert mem.output_size_in_bytes >= 4 * 16 * rows
+    assert mem.argument_size_in_bytes >= mem.output_size_in_bytes
+
+
 @pytest.mark.parametrize("queries,window", [(Q, 1),
                                             (SCAN_QUERIES, SCAN_WINDOW)])
 def test_sorted_run_search_compiles(chip, queries, window):
