@@ -18,6 +18,12 @@ Execution placement:
   ran is visible: the ``shard.mesh_lookup`` span carries
   ``placement`` ("devices" or "one_device").
 
+The jitted program is ``mesh_probe`` in either placement, so a device
+trace names it ``jit_mesh_probe``.  Traced, ``mesh_lookup`` opens a
+``kernel.launch`` span (query padding, splits, upload, the call) and a
+``kernel.fetch`` span (first to last download of the outputs), as the
+read kernels of ``kernels/`` do.
+
 64-bit keys are handled the same way kernels/scan handles them, with
 its ``lower_bound``: split into int32 halves with the low half
 XOR-biased, so signed lane compares realize unsigned 64-bit order
@@ -35,6 +41,7 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..obs import RECORDER as _OBS
+from ..obs.recorder import NULL_SPAN
 
 _BIAS = np.int32(-(1 << 31))
 
@@ -48,6 +55,7 @@ class StackedRuns:
     vlo: object  # [S, N] int32 — value low halves
     n: object    # [S] int32 — live entries per shard
     n_pad: int   # padded run length (power of two)
+    run_max: int  # longest live shard run
     steps: int   # binary-search step budget = log2(n_pad)
     n_shards: int
 
@@ -112,8 +120,8 @@ def _stack(runs: Sequence[Optional[Tuple[np.ndarray, np.ndarray]]]
         put = jnp.asarray
     return StackedRuns(
         khi=put(khi), klo=put(klo ^ _BIAS), vhi=put(vhi), vlo=put(vlo),
-        n=put(n), n_pad=n_pad, steps=max(1, n_pad.bit_length()),
-        n_shards=S)
+        n=put(n), n_pad=n_pad, run_max=max(n_live),
+        steps=max(1, n_pad.bit_length()), n_shards=S)
 
 
 def _probe_one_shard(khi, klo, vhi, vlo, n, qhi, qlo, *, steps: int):
@@ -134,9 +142,8 @@ def shard_mesh(n_shards: int):
     return jax.make_mesh((n_shards,), ("shard",))
 
 
-@functools.lru_cache(maxsize=32)
-def compiled_probe(steps: int, mesh=None):
-    """The jitted all-shard probe over ``[S, ...]`` stacked inputs: the
+def mesh_probe(khi, klo, vhi, vlo, n, qhi, qlo, *, steps: int, mesh=None):
+    """The all-shard probe over ``[S, ...]`` stacked inputs: the
     vmapped per-shard search, under ``shard_map`` on ``mesh`` (one
     device per shard) when one is given."""
     import jax
@@ -146,7 +153,17 @@ def compiled_probe(steps: int, mesh=None):
         spec = P("shard")
         fn = jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 7,
                            out_specs=(spec, spec, spec))
-    return jax.jit(fn)
+    return fn(khi, klo, vhi, vlo, n, qhi, qlo)
+
+
+@functools.lru_cache(maxsize=32)
+def compiled_probe(steps: int, mesh=None):
+    """``mesh_probe`` jitted for one step budget and placement, under
+    its own name."""
+    import jax
+    probe = functools.partial(mesh_probe, steps=steps, mesh=mesh)
+    probe.__name__ = mesh_probe.__name__
+    return jax.jit(probe)
 
 
 def placement(n_shards: int) -> str:
@@ -158,39 +175,47 @@ def placement(n_shards: int) -> str:
 
 def mesh_lookup(stacked: StackedRuns,
                 queries: Sequence[np.ndarray],
-                stats: Optional[Sequence[dict]] = None
-                ) -> List[Tuple[np.ndarray, np.ndarray]]:
+                stats: Optional[Sequence[dict]] = None,
+                span=NULL_SPAN) -> List[Tuple[np.ndarray, np.ndarray]]:
     """Probe all shards in one dispatch.  ``queries[s]`` is shard s's
     (possibly empty) int64 query vector; returns per-shard
     (found [Qs] bool, values [Qs] int64), bit-identical to probing each
     shard's sorted run with ``kernels.scan.sorted_lookup``.  ``stats``
     (one probe_stats dict per shard) takes each shard's query row of
-    the upload."""
+    the upload; the caller's open ``span`` takes the pads (``q_pad``,
+    ``n_pad``) and the longest live run (``run_max``)."""
     from ..kernels.probe import combine64, split64
     import jax.numpy as jnp
     S = stacked.n_shards
     assert len(queries) == S
     q_len = [int(np.asarray(q).shape[0]) for q in queries]
-    q_pad = 8
-    while q_pad < max(q_len + [1]):
-        q_pad <<= 1
-    qhi = np.zeros((S, q_pad), np.int32)
-    qlo = np.zeros((S, q_pad), np.int32)
-    for s, q in enumerate(queries):
-        if q_len[s]:
-            lo, hi = split64(np.asarray(q, np.int64))
-            qhi[s, :q_len[s]] = hi
-            qlo[s, :q_len[s]] = lo
-    fn = compiled_probe(stacked.steps, shard_mesh(S)
-                        if placement(S) == "devices" else None)
-    qhi, qlo = jnp.asarray(qhi), jnp.asarray(qlo ^ _BIAS)
-    _book_rows(stats, (int(qhi.nbytes) + int(qlo.nbytes)) // S)
-    found, vhi, vlo = fn(stacked.khi, stacked.klo, stacked.vhi, stacked.vlo,
-                         stacked.n, qhi, qlo)
-    found = np.asarray(found)
-    vals = combine64(np.asarray(vlo), np.asarray(vhi))
+    with _OBS.span("kernel.launch") as lsp:
+        q_pad = 8
+        while q_pad < max(q_len + [1]):
+            q_pad <<= 1
+        qhi = np.zeros((S, q_pad), np.int32)
+        qlo = np.zeros((S, q_pad), np.int32)
+        for s, q in enumerate(queries):
+            if q_len[s]:
+                lo, hi = split64(np.asarray(q, np.int64))
+                qhi[s, :q_len[s]] = hi
+                qlo[s, :q_len[s]] = lo
+        fn = compiled_probe(stacked.steps, shard_mesh(S)
+                            if placement(S) == "devices" else None)
+        qhi, qlo = jnp.asarray(qhi), jnp.asarray(qlo ^ _BIAS)
+        nbytes = int(qhi.nbytes) + int(qlo.nbytes)
+        _book_rows(stats, nbytes // S)
+        if lsp:
+            lsp.set(bytes=nbytes)
+        out = fn(stacked.khi, stacked.klo, stacked.vhi, stacked.vlo,
+                 stacked.n, qhi, qlo)
+    with _OBS.span("kernel.fetch", arrays=len(out)):
+        found, vhi, vlo = (np.asarray(o) for o in out)
+    if span:
+        span.set(q_pad=q_pad, n_pad=stacked.n_pad, run_max=stacked.run_max)
+    vals = combine64(vlo, vhi)
     return [(found[s, :q_len[s]], vals[s, :q_len[s]]) for s in range(S)]
 
 
 __all__ = ["StackedRuns", "build_stacked", "compiled_probe",
-           "mesh_lookup", "placement", "shard_mesh"]
+           "mesh_lookup", "mesh_probe", "placement", "shard_mesh"]

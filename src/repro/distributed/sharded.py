@@ -162,9 +162,11 @@ class ShardedIndex:
         self.stats["plans"] += 1
         self.last_crashed_shard = None
         t0 = time.perf_counter_ns()
-        shards = self.route(keys)
-        parts = split_by_shard(kinds, shards, self.n_shards,
-                               scan_suffix=self.scheme.startswith("prefix"))
+        with _OBS.span("shard.route", ops=n):
+            shards = self.route(keys)
+            parts = split_by_shard(
+                kinds, shards, self.n_shards,
+                scan_suffix=self.scheme.startswith("prefix"))
         result.route_ns = time.perf_counter_ns() - t0
         use_mesh = self.mesh_reads if mesh is None else mesh
         if use_mesh and n >= self.n_shards and bool((kinds == GET).all()):
@@ -316,9 +318,9 @@ class ShardedIndex:
         t0 = time.perf_counter_ns()
         with _OBS.span("shard.mesh_lookup", shards=self.n_shards,
                        ops=int(keys.shape[0]),
-                       placement=placement(self.n_shards)):
+                       placement=placement(self.n_shards)) as sp:
             per_shard = mesh_lookup(stacked, [keys[idx] for idx in parts],
-                                    stats)
+                                    stats, span=sp)
         dt = time.perf_counter_ns() - t0
         # one fused dispatch covers all shards: book each shard's share
         # of the dispatch by its query weight (sums back to the wall)
@@ -335,12 +337,13 @@ class ShardedIndex:
         for st, st0 in zip(stats, p0):
             for name in result.probe:
                 result.probe[name] += st[name] - st0[name]
-        for (found, vals), idx in zip(per_shard, parts):
-            result.found += int(found.sum())
-            if collect_results:
-                for p, f, v in zip(idx.tolist(), found.tolist(),
-                                   vals.tolist()):
-                    result.results[p] = v if f else None
+        with _OBS.span("shard.results", ops=int(keys.shape[0])):
+            for (found, vals), idx in zip(per_shard, parts):
+                result.found += int(found.sum())
+                if collect_results:
+                    for p, f, v in zip(idx.tolist(), found.tolist(),
+                                       vals.tolist()):
+                        result.results[p] = v if f else None
 
     # -- crash / recovery -------------------------------------------------
     def crash_shard(self, s: int, mode: str = "powerfail", **kw) -> None:

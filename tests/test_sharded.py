@@ -223,6 +223,7 @@ def test_whole_domain_crash_abandons_pending_replay():
 @pytest.mark.parametrize("name,factory,scheme", [
     ("P-CLHT", lambda p: PCLHT(p, n_buckets=64), "hash"),
     ("P-ART", PART, "prefix"),
+    ("P-Masstree", PMasstree, "prefix"),
 ])
 def test_mesh_read_path_matches_per_shard(name, factory, scheme):
     rng = np.random.default_rng(9)
@@ -248,6 +249,9 @@ def test_mesh_read_path_matches_per_shard(name, factory, scheme):
                                      else "one_device")
     assert r_mesh.results == r_ps.results
     assert r_mesh.found == r_ps.found
+    # and both equal a dict of what was loaded
+    ref = {int(k): int(k) + 1000 for k in keys}
+    assert r_mesh.results == [ref.get(int(k)) for k in probe]
     assert idx.stats["mesh_plans"] == 1
     # epoch-keyed cache: a write invalidates the stacked runs
     idx.execute(Plan.from_ops([("insert", 123456789, 1)]),
