@@ -20,6 +20,7 @@ commit.  Common-case insert: 2 clwb + 2 fences (paper measures 1.5/2.5).
 
 from __future__ import annotations
 
+import bisect
 from typing import Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -27,7 +28,7 @@ import numpy as np
 from ..obs import RECORDER as _OBS
 from .conditions import (Condition, ConversionSpec, IndexSnapshot,
                          RecipeIndex, register)
-from .pmem import NULL, PMem, Region
+from .pmem import NULL, WORDS_PER_LINE, PMem, Region
 
 SLOTS = 3
 BUCKET_WORDS = 8
@@ -52,6 +53,36 @@ SPEC = register(ConversionSpec(
 
 
 _M64 = (1 << 64) - 1
+
+
+def _locate(t: Region, lines: np.ndarray, keys: np.ndarray):
+    """Walk every key's bucket chain from its head (``lines``: line
+    indices of the table region) one chain level at a time, gathering
+    whole bucket rows: (the word of each key's value, -1 where the key
+    is absent; then, for each bucket row read for a key found, its op
+    and its line).  An absent key's walk is left out: the per-op path
+    walks it again."""
+    rows_of = t.cache.reshape(-1, BUCKET_WORDS)
+    word = np.full(keys.size, -1, np.int64)
+    live = np.arange(keys.size)
+    walked_ops, walked = [], []
+    while live.size:
+        rows = rows_of[lines]
+        walked_ops.append(live)
+        walked.append(lines)
+        hit = rows[:, :SLOTS] == keys[live, None]
+        found = hit.any(axis=1)
+        word[live[found]] = (lines[found] * BUCKET_WORDS + SLOTS
+                             + hit[found].argmax(axis=1))
+        more = ~found & (rows[:, 6] != NULL)
+        live = live[more]
+        lines = rows[more, 6] // BUCKET_WORDS
+    walked_ops = np.concatenate(walked_ops)
+    walked = np.concatenate(walked)
+    found = word[walked_ops] >= 0
+    if not found.all():
+        walked_ops, walked = walked_ops[found], walked[found]
+    return word, walked_ops, walked
 
 
 def _mix(key: int) -> int:
@@ -269,21 +300,27 @@ class PCLHT(RecipeIndex):
     def _apply_shard_run(self, ops: Sequence[Tuple[str, int, int]],
                          positions: Sequence[int], results: List) -> None:
         """Vectorized shard-run fast path: one shared resize-lock
-        acquisition and one vectorized bucket hash for the whole run;
-        each op then walks its chain with bulk line loads (counted like
-        the scalar walk) and commits with the *exact* scalar store
-        protocol — value word first, then the single atomic key /
-        tombstone store, flushes riding the enclosing group-commit
-        epoch.  Ops needing an overflow link or a rehash defer to the
-        scalar path; epochs bump only on actual mutation."""
+        acquisition and one vectorized bucket hash for the whole run.
+        Inside a group-commit epoch each stretch of consecutive updates
+        runs as array operations (``_update_stretch``); every other op
+        walks its chain with bulk line loads (counted like the scalar
+        walk) and commits with the *exact* scalar store protocol —
+        value word first, then the single atomic key / tombstone store,
+        flushes riding the enclosing group-commit epoch.  Ops needing
+        an overflow link or a rehash defer to the scalar path; epochs
+        bump only on actual mutation."""
         from ..kernels.partition import mix64_ref
         pmem = self.pmem
         rehash_after = False
         i, n_ops = 0, len(positions)
+        kinds, keys, values = zip(*[ops[p] for p in positions])
+        keys = np.array(keys, np.int64)
+        # where each update stretch ends: the next op that is no update
+        stops = [j for j, kind in enumerate(kinds) if kind != "update"]
+        stops.append(n_ops)
         # hash once per run: the bucket is hash % n, so only the cheap
         # vectorized mod repeats when a deferral swapped the table
-        hashes = mix64_ref(np.fromiter((ops[p][1] for p in positions),
-                                       np.int64, n_ops))
+        hashes = mix64_ref(keys)
         while i < n_ops:
             # fast section: hold the resize lock shared across the run;
             # an op needing the scalar path (rehash) breaks out so the
@@ -294,15 +331,24 @@ class PCLHT(RecipeIndex):
             try:
                 t = self._table()
                 n = pmem.load(t, 0)
-                buckets = (hashes[i:] % np.uint64(n)).astype(np.int64)
-                for head_b in buckets.tolist():
+                heads = HDR_WORDS + (hashes % np.uint64(n)).astype(
+                    np.int64) * BUCKET_WORDS
+                while i < n_ops:
+                    if kinds[i] == "update" and pmem.in_group_commit:
+                        end = stops[bisect.bisect_left(stops, i)]
+                        i, deferred, grew = self._update_stretch(
+                            t, heads, keys, values, i, end, positions,
+                            results)
+                        rehash_after |= grew
+                        if deferred is not None:
+                            break
+                        continue
                     pos = positions[i]
-                    kind, key, value = ops[pos]
-                    head = HDR_WORDS + head_b * BUCKET_WORDS
+                    head = int(heads[i])
                     pmem.lock(t, head)
                     try:
-                        r = self._run_one(t, head, kind, int(key),
-                                          int(value))
+                        r = self._run_one(t, head, kinds[i], int(keys[i]),
+                                          int(values[i]))
                     finally:
                         pmem.unlock(t, head)
                     if r is None:
@@ -326,6 +372,75 @@ class PCLHT(RecipeIndex):
         # past the remaining ops cannot change any result)
         if rehash_after and self.grow:
             self._rehash()
+
+    def _update_stretch(self, t: Region, heads: np.ndarray,
+                        keys: np.ndarray, values: Sequence[int], lo: int,
+                        hi: int, positions: Sequence[int], results: List):
+        """The updates ``lo:hi`` of a shard run, in array form, under
+        the bucket locks of their distinct heads (taken in one call, in
+        ascending slot order).  An update whose key is absent (insert
+        semantics) runs ``_run_one`` in its turn, between the array-form
+        segments before and after it; the keys found stay where they
+        are, since an insert only fills an empty slot or links a bucket
+        past the chain's end.  Returns (next op, the position deferred
+        to the scalar path or None, whether an insert asked for a
+        rehash)."""
+        pmem = self.pmem
+        words = np.array(values[lo:hi], np.int64)  # a plan holds int64
+        slots = np.unique(heads[lo:hi]).tolist()
+        pmem.lock_many(t, slots)
+        try:
+            word, walked_ops, walked = _locate(
+                t, heads[lo:hi] // BUCKET_WORDS, keys[lo:hi])
+            grew = False
+            seg = 0
+            for gap in np.flatnonzero(word < 0).tolist() + [hi - lo]:
+                if gap > seg:
+                    self._apply_updates(t, word[seg:gap], words[seg:gap])
+                    for p in positions[lo + seg:lo + gap]:
+                        results[p] = True
+                if gap == hi - lo:
+                    break
+                pos = positions[lo + gap]
+                r = self._run_one(t, int(heads[lo + gap]), "update",
+                                  int(keys[lo + gap]), values[lo + gap])
+                if r is None:  # the walks of the ops before it count
+                    pmem.account_lines(t, walked[walked_ops < gap])
+                    return lo + gap, pos, grew
+                grew |= r == "rehash_done_true"
+                results[pos] = True
+                seg = gap + 1
+            pmem.account_lines(t, walked)
+        finally:
+            pmem.unlock_many(t, slots)
+        return hi, None, grew
+
+    def _apply_updates(self, t: Region, word: np.ndarray,
+                       value: np.ndarray) -> None:
+        """Store a segment of updates whose keys are all present
+        (``word``: each one's value word): one store per distinct key,
+        of its last value, unless that equals the word already there
+        (the no-op rule).  Each store is one 8-byte atomic store, the
+        update's commit point; one clwb per dirtied line and the run's
+        fence ride the epoch, and the ops are acknowledged when it
+        closes."""
+        n = word.size
+        order = np.argsort(word, kind="stable")
+        ordered = word[order]
+        last = np.ones(n, bool)  # the last op of each key ...
+        last[:-1] = ordered[1:] != ordered[:-1]
+        if not last.all():
+            keep = np.sort(order[last])  # ... in arrival order
+            word, value = word[keep], value[keep]
+        store = t.cache[word] != value
+        if store.any():
+            if not store.all():
+                word, value = word[store], value[store]
+            self._bump_epoch()
+            self.pmem.store_scatter(t, word, value)
+            self.pmem.clwb_lines(t, (word // WORDS_PER_LINE).tolist())
+            self.pmem.fence()
+        self.probe_stats["array_writes"] += n
 
     def _run_one(self, t: Region, head: int, kind: str, key: int,
                  value: int):

@@ -34,13 +34,15 @@ from .pmem import PMem, Region, CrashPoint
 # own tallies ride along: ``exports`` (snapshot exports built, deltas
 # included), ``upload_bytes`` (host->device bytes: prepared exports,
 # delta patches and query batches), ``scalar_reads`` (keys answered by
-# a per-key lookup), and ``delta_exports`` / ``delta_rows`` (exports
-# made by patching the stale snapshot's changed rows, and those rows).
+# a per-key lookup), ``delta_exports`` / ``delta_rows`` (exports
+# made by patching the stale snapshot's changed rows, and those rows);
+# the write path's ``array_writes`` counts the ops a shard run applied
+# in array form (P-CLHT's update stretches).
 PROBE_STAT_KEYS = ("fp_compares", "candidates", "fp_hits",
                    "fp_false_positives", "pm_load_words",
                    "optimistic_probes", "optimistic_retries",
                    "exports", "upload_bytes", "scalar_reads",
-                   "delta_exports", "delta_rows")
+                   "delta_exports", "delta_rows", "array_writes")
 
 
 def _export_rows(arrays: Any) -> int:

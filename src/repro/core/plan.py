@@ -72,7 +72,8 @@ NULL_KEY = 0
 _NULL_KEY_MSG = "key 0 is the indexes' empty-slot word; keys start at 1"
 # probe_stats keys whose per-wave deltas ride on the plan.wave span
 _WAVE_TALLIES = ("optimistic_retries", "exports", "upload_bytes",
-                 "scalar_reads", "delta_exports", "delta_rows")
+                 "scalar_reads", "delta_exports", "delta_rows",
+                 "array_writes")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -44,7 +44,8 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from typing import Callable, Dict, Iterable, List, Optional, Set, Tuple
+from typing import (Callable, Dict, Iterable, List, Optional, Sequence,
+                    Set, Tuple)
 
 import numpy as np
 
@@ -58,6 +59,10 @@ NULL = 0  # null pointer / empty-key sentinel used across indexes
 
 _M64 = (1 << 64) - 1
 _SIGN64 = 1 << 63
+_LINE_WORDS = np.arange(WORDS_PER_LINE, dtype=np.int64)
+# a touched line's key: (rid << _RID_SHIFT) + line, one int (a region
+# holds fewer than 2**40 lines)
+_RID_SHIFT = 40
 
 
 class CrashPoint(Exception):
@@ -133,7 +138,7 @@ class PMem:
         self._lock_mutex = threading.Lock()  # protects lock-state only
         self.max_spins = max_spins
         self.counters = OpCounters()
-        self._touched_lines: Set[Tuple[int, int]] = set()
+        self._touched_lines: Set[int] = set()  # see _RID_SHIFT
         self.rng = np.random.default_rng(seed)
         # Crash injection
         self.crash_after_store: Optional[int] = None
@@ -144,7 +149,7 @@ class PMem:
         self.alloc_log: List[int] = []
         # Group-commit epoch state (see group_commit())
         self._group_depth = 0
-        self._group_lines: Set[Tuple[int, int]] = set()  # (rid, line)
+        self._group_lines: Dict[int, Set[int]] = {}  # rid -> lines
         self._group_fence_wanted = False
 
     # ------------------------------------------------------------------
@@ -163,8 +168,8 @@ class PMem:
 
     def track_lines(self, region: Region, limit: int) -> Set[int]:
         """Arm a fresh record of the lines of ``region`` that stores
-        touch from now on (``store`` and ``store_bulk``), and return
-        it.  Re-arming replaces the record; one that would pass
+        touch from now on (``store``, ``store_bulk``, ``store_scatter``),
+        and return it.  Re-arming replaces the record; one that would pass
         ``limit`` lines is dropped (``region.written`` becomes None), so
         a holder can tell a complete record by identity.  Only a region
         that asks is tracked: the others pay one attribute check per
@@ -219,6 +224,46 @@ class PMem:
             if len(written) > region.written_limit:
                 region.written = None
 
+    def store_scatter(self, region: Region, idx: np.ndarray,
+                      values: np.ndarray) -> None:
+        """``store`` of ``values[i]`` to word ``idx[i]`` for each i, in
+        order, as array operations: each word is its own 8-byte atomic
+        store and its own crash point, so a crash armed inside the
+        scatter lands after the first k words (``CrashPoint`` then
+        raises, as ``store`` would on word k + 1).  ``idx`` holds
+        distinct words; ``values`` are int64 words (wrapped already)."""
+        n = len(idx)
+        cut = n
+        if self.crash_after_store is not None:
+            cut = min(n, self._stores_until_crash)
+            self._stores_until_crash -= cut
+        self.crash_calls += cut
+        if cut:
+            idx = idx[:cut]
+            region.cache[idx] = values[:cut]
+            lines = (idx // WORDS_PER_LINE).tolist()
+            region.dirty.update(lines)
+            region.stores += cut
+            self.counters.stores += cut
+            written = region.written
+            if written is not None:
+                written.update(lines)
+                if len(written) > region.written_limit:
+                    region.written = None
+        if cut < n:
+            self._maybe_crash()  # word cut + 1: raises CrashPoint
+
+    def account_lines(self, region: Region, lines: np.ndarray) -> None:
+        """Count the loads of whole lines that an array gather read from
+        ``region.cache`` (``lines``: line indices, a repeat for each time
+        a line was read) exactly as ``load_bulk`` of each would: 8 loads
+        apiece, and each line not yet touched in the op."""
+        self.counters.loads += WORDS_PER_LINE * len(lines)
+        touched = self._touched_lines
+        before = len(touched)
+        touched.update((lines + (region.rid << _RID_SHIFT)).tolist())
+        self.counters.lines_touched += len(touched) - before
+
     def load_bulk(self, region: Region, start: int, n: int) -> np.ndarray:
         """Vectorized multi-word load (counts ``n`` loads and every line
         overlapped, so the batched write paths keep the Table-4 proxies
@@ -226,10 +271,10 @@ class PMem:
         self.counters.loads += n
         first = start // WORDS_PER_LINE
         last = (start + max(n, 1) - 1) // WORDS_PER_LINE
-        rid = region.rid
+        base = region.rid << _RID_SHIFT
         touched = self._touched_lines
         for line in range(first, last + 1):
-            key = (rid, line)
+            key = base + line
             if key not in touched:
                 touched.add(key)
                 self.counters.lines_touched += 1
@@ -237,7 +282,7 @@ class PMem:
 
     def load(self, region: Region, idx: int) -> int:
         self.counters.loads += 1
-        key = (region.rid, region.line_of(idx))
+        key = (region.rid << _RID_SHIFT) + idx // WORDS_PER_LINE
         if key not in self._touched_lines:
             self._touched_lines.add(key)
             self.counters.lines_touched += 1
@@ -259,12 +304,28 @@ class PMem:
         recorded once and flushed (and counted) at epoch close."""
         line = region.line_of(idx)
         if self._group_depth:
-            self._group_lines.add((region.rid, line))
+            lines = self._group_lines.get(region.rid)
+            if lines is None:
+                lines = self._group_lines[region.rid] = set()
+            lines.add(line)
             return
         if line in region.dirty:
             region.pending.add(line)
             region.dirty.discard(line)
         self.counters.clwb += 1
+
+    def clwb_lines(self, region: Region, lines: Iterable[int]) -> None:
+        """``clwb`` of each of ``lines`` (line indices) of ``region``:
+        inside a group-commit epoch recorded once each for the close."""
+        if self._group_depth:
+            group = self._group_lines.get(region.rid)
+            if group is None:
+                self._group_lines[region.rid] = set(lines)
+            else:
+                group.update(lines)
+            return
+        for line in lines:
+            self.clwb(region, line * WORDS_PER_LINE)
 
     def flush_range(self, region: Region, lo: int, hi: int) -> None:
         """clwb every line overlapping words [lo, hi)."""
@@ -284,12 +345,23 @@ class PMem:
     def _fence_now(self) -> None:
         self.counters.fence += 1
         for region in self.regions.values():
-            if region.pending:
-                for line in region.pending:
+            lines = region.pending
+            if not lines:
+                continue
+            # a fresh set takes the clwbs that come after this point:
+            # none is dropped by clearing the set copied here
+            region.pending = set()
+            if len(lines) == 1:  # a scalar op's persist: one slice
+                for line in lines:
                     lo = line * WORDS_PER_LINE
-                    hi = min(lo + WORDS_PER_LINE, region.n_words)
+                    hi = lo + WORDS_PER_LINE  # a slice stops at the end
                     region.pm[lo:hi] = region.cache[lo:hi]
-                region.pending.clear()
+            else:  # one fancy-index copy of every pending line's words
+                words = (np.fromiter(lines, np.int64, len(lines))[:, None]
+                         * WORDS_PER_LINE + _LINE_WORDS).ravel()
+                if region.n_words % WORDS_PER_LINE:
+                    words = words[words < region.n_words]
+                region.pm[words] = region.cache[words]
 
     def persist(self, region: Region, idx: int) -> None:
         """Convenience: clwb + fence for one word's line."""
@@ -314,24 +386,29 @@ class PMem:
         persists."""
         return _GroupCommit(self)
 
+    @property
+    def in_group_commit(self) -> bool:
+        """Whether a group-commit epoch is open (clwb/fence deferred)."""
+        return self._group_depth > 0
+
     def _close_group(self) -> None:
-        lines = sorted(self._group_lines)
-        self._group_lines = set()
-        wanted = self._group_fence_wanted or bool(lines)
+        groups, self._group_lines = self._group_lines, {}
+        wanted = self._group_fence_wanted or bool(groups)
         self._group_fence_wanted = False
-        for rid, line in lines:
+        for rid, lines in groups.items():
             region = self.regions.get(rid)
             if region is None:
                 continue  # freed mid-group (CoW swap garbage)
-            if line in region.dirty:
-                region.pending.add(line)
-                region.dirty.discard(line)
-            self.counters.clwb += 1
+            moved = lines & region.dirty
+            if moved:
+                region.pending |= moved
+                region.dirty -= moved
+            self.counters.clwb += len(lines)
         if wanted:
             self._fence_now()
 
     def _abandon_group(self) -> None:
-        self._group_lines = set()
+        self._group_lines = {}
         self._group_fence_wanted = False
 
     # ------------------------------------------------------------------
@@ -355,6 +432,41 @@ class PMem:
     def unlock(self, region: Region, slot: int = 0) -> None:
         with self._lock_mutex:
             self.locks.pop((region.rid, slot), None)
+
+    def lock_many(self, region: Region, slots: Sequence[int]) -> None:
+        """Exclusive locks on ``slots`` (distinct, ascending), taken in
+        that order: as many as are free under each acquisition of the
+        lock mutex, then a spin on the first held one.  Writers that
+        take one lock, or several in ascending order, cannot deadlock
+        against it.  The spin guard is ``lock``'s, per slot waited on;
+        when it trips, the slots taken are released first."""
+        locks = self.locks
+        keys = [(region.rid, s) for s in slots]
+        with self._lock_mutex:
+            if locks.keys().isdisjoint(keys):  # all free: take them all
+                locks.update(dict.fromkeys(keys, True))
+                return
+        taken = spins = 0
+        while True:
+            before = taken
+            with self._lock_mutex:
+                for key in keys[taken:]:
+                    if locks.get(key):
+                        break
+                    locks[key] = True
+                    taken += 1
+            if taken == len(keys):
+                return
+            spins = spins + 1 if taken == before else 1
+            if spins >= self.max_spins:
+                self.unlock_many(region, slots[:taken])
+                raise DeadlockError(
+                    f"lock ({region.name},{slots[taken]}) spun out")
+
+    def unlock_many(self, region: Region, slots: Sequence[int]) -> None:
+        with self._lock_mutex:
+            for s in slots:
+                self.locks.pop((region.rid, s), None)
 
     def holds_lock(self, region: Region, slot: int = 0) -> bool:
         return bool(self.locks.get((region.rid, slot)))
