@@ -26,10 +26,9 @@ from ..obs import MetricsRegistry, MetricsView
 
 # public index kinds; aliases accept the paper's P-* names (any case).
 # "cceh", "fastfair" and "level"/"levelhashing" are the hand-crafted
-# PM baselines on the same plan surface — the head-to-head comparators
-# of the shard-scaling sweep and the adversarial workload matrix
-# (benchmarks/matrix.py).  With the Level hashing port, all eight
-# indexes of the paper's comparison are plan-executable.
+# PM baselines on the same plan surface — the paper's head-to-head
+# comparators.  With the Level hashing port, all eight indexes of the
+# paper's comparison are plan-executable.
 _KINDS = {
     "clht": PCLHT,
     "art": PART,
@@ -211,8 +210,8 @@ class Session:
         """Shard count (1 for an unsharded session)."""
         return getattr(self.index, "n_shards", 1)
 
-    def streams(self, n: int, *, collect_results: bool = True,
-                lat_hist=None) -> "StreamDriver":
+    def streams(self, n: int, *, collect_results: bool = True
+                ) -> "StreamDriver":
         """Multi-session harness: ``n`` independent client streams over
         this session's index.  Each ``driver.streams[i]`` submits plans
         independently; ``driver.tick()``/``driver.run()`` admit
@@ -224,7 +223,7 @@ class Session:
         ``stats``.  See ``repro.distributed.streams``."""
         from ..distributed import StreamDriver
         return StreamDriver(self.index, n, collect_results=collect_results,
-                            lat_hist=lat_hist, metrics=self.metrics)
+                            metrics=self.metrics)
 
     # -- plan execution ---------------------------------------------------
     def execute(self, plan: Plan, *, force_kernel: bool = False

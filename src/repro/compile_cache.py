@@ -1,6 +1,6 @@
 """Where JAX keeps its persistent compilation cache.
 
-Entry points that compile (``chip_smoke.py``, ``benchmarks/run.py``)
+Entry points that compile (``chip_smoke.py``, ``bench/run.py``)
 call ``configure()`` before their first compile; importing the package
 does not.  ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own
 setting and is left alone.  Otherwise the cache goes to one fixed

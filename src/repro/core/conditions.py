@@ -153,7 +153,8 @@ class RecipeIndex:
     # (kernels/probe/fingerprint) and the probe kernels gather full
     # keys only on fingerprint hits.  Results are bit-identical either
     # way; flipping this off switches the probe-traffic model to
-    # full-key gathers for every lane (the A/B the benchmarks measure).
+    # full-key gathers for every lane (the A/B tests/test_fingerprints.py
+    # pins; no served path turns it off).
     fingerprints = True
 
     def __init__(self, pmem: PMem):
@@ -726,7 +727,7 @@ class RecipeIndex:
         tolerate and writes fix inconsistencies.  (Hand-crafted baselines
         override this with their real recovery algorithms.)"""
 
-    # -- introspection for tests/benchmarks -------------------------------
+    # -- introspection for tests and oracles -----------------------------
     def keys(self) -> Iterator[int]:
         raise NotImplementedError
 

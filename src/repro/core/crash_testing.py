@@ -334,6 +334,23 @@ def plan_prefix_states(ops: Sequence[Op],
     return states, model
 
 
+def prime_export(index) -> None:
+    """Rebuild ``index``'s batched-read export at its current (restored)
+    image.  ``PMSnapshot`` does not roll back the monotonic store
+    counters, so the cached export from a previous run always looks
+    foreign; re-exporting re-arms the optimistic overlap path
+    identically on a dry run and on every armed re-run, so each re-run
+    passes the crash points the dry run recorded."""
+    if not hasattr(index, "snapshot"):
+        return
+    index._snapshot = None
+    index._accounted_stores = index._write_account()
+    try:
+        index.snapshot()
+    except NotImplementedError:
+        pass
+
+
 def plan_crash_sweep(
     factory: Callable[[PMem], object],
     ops: Sequence[Op],
@@ -386,22 +403,7 @@ def plan_crash_sweep(
         base = plan_prefix_states(setup_ops)[1]
     snap = PMSnapshot(pmem, index)
 
-    def prime() -> None:
-        # rebuild the batched-read export at the (restored) image:
-        # PMSnapshot does not roll back the monotonic store counters,
-        # so the cached export from a previous run always looks
-        # foreign — re-exporting re-arms the optimistic overlap path
-        # identically on the dry run and on every armed re-run
-        if not hasattr(index, "snapshot"):
-            return
-        index._snapshot = None
-        index._accounted_stores = index._write_account()
-        try:
-            index.snapshot()
-        except NotImplementedError:
-            pass
-
-    prime()
+    prime_export(index)
     vpoints: List[int] = []
     boundaries = group_commit_boundaries(
         pmem, lambda: vpoints.extend(validation_points(
@@ -428,7 +430,7 @@ def plan_crash_sweep(
     report.n_ops_tested = len(ops)
     for off in offsets:
         snap.restore(pmem)
-        prime()
+        prime_export(index)
         report.n_crash_states += 1
         tag = f"plan@store{off}"
         pmem.arm_crash(after_stores=off)
@@ -460,7 +462,7 @@ def plan_crash_sweep(
             report.consistency_failures.append(
                 f"{tag}: post-crash write of {fresh} lost")
     snap.restore(pmem)
-    prime()
+    prime_export(index)
     index.execute(plan, collect_results=False)
     if dict(index.items()) != model:
         report.consistency_failures.append(

@@ -14,8 +14,7 @@ traverse more bytes — the cache-behavior analogue).
 from __future__ import annotations
 
 import dataclasses
-import time
-from typing import List, Optional, Sequence, Tuple
+from typing import List, Sequence, Tuple
 
 import numpy as np
 
@@ -46,8 +45,8 @@ class Workload:
     run_ops: List[Op]  # the measured phase
     scan_lengths: List[int]
     # generator knobs (distribution, theta, keyspace, ...) — filled by
-    # the adversarial matrix generator (repro.data.workloads) so
-    # benchmark rows can label themselves from the workload alone
+    # the adversarial matrix generator (repro.data.workloads) so a
+    # run can label itself from the workload alone
     meta: dict = dataclasses.field(default_factory=dict)
 
 
@@ -133,17 +132,8 @@ class PhaseExecutor:
     across the read/write boundary, so the mixed YCSB mixes (A/B/D/F)
     run fully batched instead of flushing on the first key collision.
     Op results, found counts, and scanned-record counts match the
-    scalar execution exactly (asserted in ``benchmarks/ycsb.py`` and
-    ``tests/test_write_batch.py``).
-
-    ``buffered=True`` keeps the pre-plan buffer-and-flush engine (one
-    buffer per protocol, flushed on the first cross-buffer key
-    conflict) as the measured baseline for ``benchmarks/ycsb.
-    bench_mixed_plan``.  Its historical double-flush is fixed here:
-    scans and lookups are both reads and never conflict — back-to-back
-    scans over identical start keys share a buffer, and a scan no
-    longer dumps the read buffer (nor a lookup the scan buffer); only
-    writes still fence both.
+    scalar execution exactly (``tests/test_write_batch.py``,
+    ``tests/test_batched_lookup.py``, ``tests/test_scan_batch.py``).
 
     Scans execute as "first ``aux`` live records from ``key``"
     (``index.scan``) — real YCSB-E semantics, identical on the scalar
@@ -151,24 +141,16 @@ class PhaseExecutor:
     """
 
     def __init__(self, index, *, batch_lookups: bool = False,
-                 max_batch: int = 4096, buffered: bool = False,
-                 lat_hist=None):
+                 max_batch: int = 4096):
         self.index = index
         self.batch_lookups = batch_lookups
         self.max_batch = max_batch
-        self.buffered = buffered
-        self.lat_hist = lat_hist  # optional obs.Histogram of per-op ns
         self.done = {"insert": 0, "update": 0, "delete": 0, "lookup": 0,
                      "scan": 0, "found": 0, "scanned": 0, "acked": 0,
                      "batches": 0, "scan_batches": 0, "write_batches": 0,
                      "plans": 0, "waves": 0, "wave_ops": 0}
-        self._pending: List[int] = []
-        self._pending_keys: set = set()
-        self._pending_scans: List[Tuple[int, int]] = []
-        self._pending_writes: List[Op] = []
-        self._pending_write_keys: set = set()
 
-    # -- plan mode (the default batched path) -----------------------------
+    # -- plan mode (the batched path) -------------------------------------
     def _run_plans(self, ops: Sequence[Op]) -> dict:
         from .plan import DELETE, GET, PUT, Plan, SCAN, UPDATE
         code = {"lookup": GET, "insert": PUT, "update": UPDATE,
@@ -185,16 +167,10 @@ class PhaseExecutor:
         done["delete"] += int(cnt[DELETE])
         done["scan"] += int(cnt[SCAN])
         mb = self.max_batch
-        hist = self.lat_hist
         for lo in range(0, n, mb):
             plan = Plan.from_arrays(kinds[lo:lo + mb], keys[lo:lo + mb],
                                     aux[lo:lo + mb])
-            if hist is not None:
-                t0 = time.perf_counter_ns()
             res = self.index.execute(plan, collect_results=False)
-            if hist is not None:
-                # amortized per-op latency: the batch's ops share its cost
-                hist.record_batch(time.perf_counter_ns() - t0, len(plan))
             done["found"] += res.found
             done["acked"] += res.acked
             done["scanned"] += res.scanned
@@ -210,88 +186,12 @@ class PhaseExecutor:
                     done["write_batches"] += 1
         return done
 
-    # -- buffered legacy mode (the PR-4 baseline) -------------------------
-    def _flush_lookups(self) -> None:
-        if not self._pending:
-            return
-        results = self.index._lookup_batch(self._pending)
-        self.done["lookup"] += len(self._pending)
-        self.done["found"] += sum(r is not None for r in results)
-        self.done["batches"] += 1
-        self._pending.clear()
-        self._pending_keys.clear()
-
-    def _flush_scans(self) -> None:
-        if not self._pending_scans:
-            return
-        starts = [s for s, _ in self._pending_scans]
-        counts = [c for _, c in self._pending_scans]
-        results = self.index._scan_batch(starts, counts)
-        self.done["scan"] += len(starts)
-        self.done["scanned"] += sum(len(r) for r in results)
-        self.done["scan_batches"] += 1
-        self._pending_scans.clear()
-
-    def _flush_writes(self) -> None:
-        if not self._pending_writes:
-            return
-        results = self.index._write_batch(self._pending_writes)
-        done = self.done
-        for kind, _, _ in self._pending_writes:
-            done[kind] += 1
-        done["acked"] += sum(bool(r) for r in results)
-        done["write_batches"] += 1
-        self._pending_writes.clear()
-        self._pending_write_keys.clear()
-
-    def _flush(self) -> None:
-        self._flush_lookups()
-        self._flush_scans()
-        self._flush_writes()
-
-    def _run_buffered(self, ops: Sequence[Op]) -> dict:
-        done = self.done
-        pending, max_batch = self._pending, self.max_batch
-        pending_keys = self._pending_keys
-        pending_scans = self._pending_scans
-        pending_writes = self._pending_writes
-        pending_write_keys = self._pending_write_keys
-        for kind, key, aux in ops:
-            if kind == "lookup":
-                if key in pending_write_keys:
-                    self._flush_writes()  # must observe that write
-                pending.append(key)
-                pending_keys.add(key)
-                if len(pending) >= max_batch:
-                    self._flush_lookups()
-            elif kind == "scan":
-                self._flush_writes()  # a scan may observe any write
-                pending_scans.append((key, aux))
-                if len(pending_scans) >= max_batch:
-                    self._flush_scans()
-            else:  # insert / update / delete
-                self._flush_scans()  # buffered scans precede this write
-                if key in pending_keys:
-                    self._flush_lookups()  # those reads precede it too
-                pending_writes.append((kind, key, aux))
-                pending_write_keys.add(key)
-                if len(pending_writes) >= max_batch:
-                    self._flush_writes()
-        self._flush()
-        return done
-
     def run(self, ops: Sequence[Op]) -> dict:
         if self.batch_lookups:
-            if self.buffered:
-                return self._run_buffered(ops)
             return self._run_plans(ops)
         done = self.done
         index, lookup = self.index, self.index.lookup
-        hist = self.lat_hist
-        timer = time.perf_counter_ns
         for kind, key, aux in ops:
-            if hist is not None:
-                t0 = timer()
             if kind == "lookup":
                 if lookup(key) is not None:
                     done["found"] += 1
@@ -308,25 +208,17 @@ class PhaseExecutor:
                     r = index.delete(key)
                 done["acked"] += bool(r)
                 done[kind] += 1
-            if hist is not None:
-                hist.record(timer() - t0)
         return done
 
 
 def run_workload(index, wl: Workload, *, phase: str = "run",
-                 batch_lookups: bool = False, max_batch: int = 4096,
-                 buffered: bool = False, lat_hist=None) -> dict:
+                 batch_lookups: bool = False, max_batch: int = 4096) -> dict:
     """Execute a phase; returns op counts (throughput measured by caller).
     With ``batch_lookups`` the op stream runs as operation plans of
     ``max_batch`` ops through ``index.execute`` — conflict-wave
     scheduling over the Pallas probe/scan kernels and the sharded
-    group-commit write path, for all five converted indexes.
-    ``buffered`` selects the pre-plan buffer-and-flush baseline
-    instead (benchmark honesty comparisons only).  ``lat_hist`` (an
-    ``obs.Histogram``) collects per-op latency in ns: exact per op on
-    the scalar path, amortized per plan chunk on the batched path."""
+    group-commit write path, for all five converted indexes."""
     ops = wl.load_ops if phase == "load" else wl.run_ops
     ex = PhaseExecutor(index, batch_lookups=batch_lookups,
-                       max_batch=max_batch, buffered=buffered,
-                       lat_hist=lat_hist)
+                       max_batch=max_batch)
     return ex.run(ops)

@@ -34,8 +34,8 @@ ShardedIndex) drives them unchanged:
   route them with ``hash`` or the ``prefix@58`` scheme
   (kernels/partition) instead.
 
-``replay`` is the dict/sorted-dict oracle the tests and the
-``benchmarks/matrix.py`` honesty asserts compare every index against.
+``replay`` is the dict/sorted-dict oracle the tests compare every
+index against.
 """
 
 from __future__ import annotations
@@ -202,7 +202,7 @@ def matrix_workload(mix: str, n_load: int, n_run: int, *,
     default ``'int'`` keyspace matches ``generate``'s ranges (loads in
     [1, 2^60), fresh inserts in [2^60, 2^61)).  Fixed ``seed`` makes
     the whole schedule deterministic.  The workload's knobs are kept
-    on ``Workload.meta`` for benchmark row labeling."""
+    on ``Workload.meta`` for labeling."""
     mix_spec = WORKLOADS[mix]
     if dist not in DISTRIBUTIONS:
         raise ValueError(f"unknown distribution {dist!r}; "
@@ -295,8 +295,7 @@ def replay(load_ops: Sequence[Op], run_ops: Sequence[Op] = (),
     Plan execution preserves per-key program order and scan/write
     ordering, so its found/acked/scanned counts — on ANY plan-surface
     index, batched or scalar, sharded or not — must equal this
-    replay's (asserted per index in tests/test_workloads.py and on
-    every ``benchmarks/matrix.py`` row)."""
+    replay's (asserted per index in tests/test_workloads.py)."""
     res = ReplayResult(model={} if model is None else dict(model))
     m = res.model
     for kind, key, aux in load_ops:

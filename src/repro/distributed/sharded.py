@@ -34,19 +34,11 @@ The crashed shard's sub-plan is remembered; ``recover_shard`` re-runs
 the shard's (trivial) RECIPE recovery and optionally replays exactly
 that sub-plan — never a sibling's — on top of the shard's
 plan-prefix-consistent image.
-
-Throughput accounting: shard sub-plans are timed individually and a
-``ShardedPlanResult`` reports both the serial wall time and the
-*critical path* (routing + the slowest shard + merge) — the tick time
-of an S-device mesh executing shard waves concurrently.  On a 1-core
-host the wall clock serializes the shards; benchmarks report both
-columns (docs/SHARDING.md, "Reporting model").
 """
 
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
@@ -90,23 +82,9 @@ class ShardedPMem:
 
 @dataclasses.dataclass
 class ShardedPlanResult(PlanResult):
-    """``PlanResult`` plus the scale-out telemetry drivers report."""
+    """``PlanResult`` plus whether the mesh fan-out answered it."""
 
-    shard_ops: List[int] = dataclasses.field(default_factory=list)
-    shard_ns: List[int] = dataclasses.field(default_factory=list)
-    route_ns: int = 0
-    merge_ns: int = 0
     mesh: bool = False
-
-    @property
-    def critical_ns(self) -> int:
-        """Modeled S-device tick time: serial routing + the slowest
-        shard's sub-plan + serial merge.  Equals wall time at S=1."""
-        return self.route_ns + max(self.shard_ns, default=0) + self.merge_ns
-
-    @property
-    def wall_ns(self) -> int:
-        return self.route_ns + sum(self.shard_ns) + self.merge_ns
 
 
 class ShardedIndex:
@@ -161,13 +139,11 @@ class ShardedIndex:
             return result
         self.stats["plans"] += 1
         self.last_crashed_shard = None
-        t0 = time.perf_counter_ns()
         with _OBS.span("shard.route", ops=n):
             shards = self.route(keys)
             parts = split_by_shard(
                 kinds, shards, self.n_shards,
                 scan_suffix=self.scheme.startswith("prefix"))
-        result.route_ns = time.perf_counter_ns() - t0
         use_mesh = self.mesh_reads if mesh is None else mesh
         if use_mesh and n >= self.n_shards and bool((kinds == GET).all()):
             self._execute_mesh(keys, parts, result, collect_results)
@@ -186,11 +162,8 @@ class ShardedIndex:
         sub_results: List[Optional[PlanResult]] = [None] * self.n_shards
         for s, idx in enumerate(parts):
             if idx.size == 0:
-                result.shard_ops.append(0)
-                result.shard_ns.append(0)
                 continue
             sub = Plan.from_arrays(kinds[idx], keys[idx], aux[idx])
-            t0 = time.perf_counter_ns()
             with _OBS.span("shard.plan", shard=s, ops=int(idx.size)) as sp:
                 c0 = self.pmems[s].counters.snapshot() if sp else None
                 try:
@@ -209,8 +182,6 @@ class ShardedIndex:
                     sp.set(stores=d.stores, loads=d.loads, clwb=d.clwb,
                            fence=d.fence, lines_touched=d.lines_touched,
                            crashed=s == crashed)
-            result.shard_ns.append(time.perf_counter_ns() - t0)
-            result.shard_ops.append(int(idx.size))
             self.stats["shard_subplans"] += 1
             sub_results[s] = r
             if r is not None:
@@ -229,11 +200,9 @@ class ShardedIndex:
             # whether to power-fail + recover the affected shard
             self.last_crashed_shard = crashed
             raise CrashPoint()
-        t0 = time.perf_counter_ns()
         if collect_results or has_scan:
             self._scatter(kinds, aux, parts, sub_results, result,
                           collect_results)
-        result.merge_ns = time.perf_counter_ns() - t0
 
     def _scatter(self, kinds, aux, parts, sub_results, result,
                  collect_results: bool) -> None:
@@ -315,19 +284,11 @@ class ShardedIndex:
                                lines_touched=d.lines_touched)
             self._mesh_cache = (ek, build_stacked(runs, stats))
         stacked = self._mesh_cache[1]
-        t0 = time.perf_counter_ns()
         with _OBS.span("shard.mesh_lookup", shards=self.n_shards,
                        ops=int(keys.shape[0]),
                        placement=placement(self.n_shards)) as sp:
             per_shard = mesh_lookup(stacked, [keys[idx] for idx in parts],
                                     stats, span=sp)
-        dt = time.perf_counter_ns() - t0
-        # one fused dispatch covers all shards: book each shard's share
-        # of the dispatch by its query weight (sums back to the wall)
-        total_q = max(1, sum(int(idx.size) for idx in parts))
-        for s, idx in enumerate(parts):
-            result.shard_ops.append(int(idx.size))
-            result.shard_ns.append(dt * int(idx.size) // total_q)
         result.wave_kinds.append("read")
         result.wave_widths.append(int(keys.shape[0]))
         result.mesh = True

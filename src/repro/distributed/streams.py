@@ -17,18 +17,10 @@ results are independent of admission order — the property the
 cross-stream tests pin against a sequential per-stream oracle.  Within
 a stream, plan submission order is program order (a stream's next plan
 is not admitted before its earlier one).
-
-Per-op latency attribution is batch-amortized: a tick's cost is spread
-over the ops it completed (``obs.Histogram.record_batch``).  When the
-index is a ``ShardedIndex`` the driver books the *modeled* S-device
-tick time (``critical_ns`` — routing + slowest shard + merge) and
-keeps the serial wall time in ``stats["wall_ns"]``; for a plain index
-the two are the same measurement.
 """
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import Any, Deque, List, Optional, Tuple
 
@@ -37,7 +29,6 @@ import numpy as np
 from ..core.plan import Plan, PlanResult
 from ..kernels.conflict import conflict_any
 from ..obs import RECORDER as _OBS
-from ..obs import Histogram
 
 
 class StreamTicket:
@@ -81,16 +72,12 @@ class StreamDriver:
                 "multi_stream_ticks")
 
     def __init__(self, index, n_streams: int, *,
-                 collect_results: bool = True,
-                 lat_hist: Optional[Histogram] = None,
-                 metrics=None):
+                 collect_results: bool = True, metrics=None):
         self.index = index
         self.streams = [ClientStream(self, i) for i in range(n_streams)]
         self.collect_results = collect_results
-        self.lat_hist = lat_hist
         self.stats = {"ticks": 0, "admitted_plans": 0, "deferred_plans": 0,
                       "merged_ops": 0, "multi_stream_ticks": 0,
-                      "wall_ns": 0, "critical_ns": 0,
                       "found": 0, "acked": 0, "scanned": 0}
         # optional obs.MetricsRegistry: admission telemetry (above all
         # the deferred-plan contention counter) mirrored live so it is
@@ -158,18 +145,12 @@ class StreamDriver:
         return admitted, merged
 
     def _scatter(self, admitted: List[Tuple["ClientStream", StreamTicket]],
-                 res: PlanResult, wall: int, tick_no: int) -> None:
-        """Book a completed merged plan: tally stats, record latency,
-        slice per-op results back to the stream tickets."""
-        modeled = getattr(res, "critical_ns", 0) or wall
-        self.stats["wall_ns"] += wall
-        self.stats["critical_ns"] += modeled
+                 res: PlanResult, tick_no: int) -> None:
+        """Book a completed merged plan: tally stats and slice per-op
+        results back to the stream tickets."""
         self.stats["found"] += res.found
         self.stats["acked"] += res.acked
         self.stats["scanned"] += res.scanned
-        if self.lat_hist is not None:
-            self.lat_hist.record_batch(modeled, sum(
-                len(t.plan) for _, t in admitted))
         at = 0
         for stream, ticket in admitted:
             width = len(ticket.plan)
@@ -186,13 +167,11 @@ class StreamDriver:
         admitted, merged = self._admit_tick()
         if not admitted:
             return None
-        n_ops = len(merged)
-        t0 = time.perf_counter_ns()
-        with _OBS.span("streams.tick", streams=len(admitted), ops=n_ops):
+        with _OBS.span("streams.tick", streams=len(admitted),
+                       ops=len(merged)):
             res = self.index.execute(
                 merged, collect_results=self.collect_results, **execute_kw)
-        wall = time.perf_counter_ns() - t0
-        self._scatter(admitted, res, wall, self.stats["ticks"])
+        self._scatter(admitted, res, self.stats["ticks"])
         return res
 
     def run(self, max_ticks: int = 100_000, **execute_kw) -> int:
@@ -234,7 +213,7 @@ class StreamDriver:
         n = 0
         while self._inflight and self._inflight[0][0].done:
             ticket, admitted, tick_no = self._inflight.pop(0)
-            self._scatter(admitted, ticket.wait(), ticket.exec_ns, tick_no)
+            self._scatter(admitted, ticket.wait(), tick_no)
             n += 1
         return n
 

@@ -80,16 +80,6 @@ class Histogram:
         self.n += int(vals.size)
         self.total += int(vals.sum())
 
-    def record_batch(self, total: int, n: int) -> None:
-        """Amortized recording for batched dispatches: ``n`` ops that
-        together took ``total`` — each is booked at the mean cost (the
-        honest per-op latency a batch driver can attribute)."""
-        if n <= 0:
-            return
-        self.counts[bucket_index(int(total) // n)] += n
-        self.n += n
-        self.total += int(total)
-
     @property
     def mean(self) -> float:
         return self.total / self.n if self.n else 0.0

@@ -3,9 +3,9 @@ the double-buffered pipeline layer (``pipeline``).
 
 ``pipeline`` imports eagerly (plans + obs only); the engine — which
 pulls in the jax compute plane — resolves lazily on first attribute
-access, so plan-level drivers (StreamDriver benchmarks, the chaos
-harness's index-level sweeps) can use ``AsyncExporter``/
-``PlanPipeline`` without paying the model stack import.
+access, so plan-level drivers (``StreamDriver``, index-level
+recovery sweeps) can use ``AsyncExporter``/``PlanPipeline`` without
+paying the model stack import.
 """
 
 from .pipeline import AsyncExporter, PlanPipeline, PlanTicket

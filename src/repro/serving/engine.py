@@ -361,8 +361,7 @@ class Server:
         self.metrics.gauge("sessions_connected").set(self._next_sid - 1)
         return ServerSession(self, sid)
 
-    def streams(self, n: int, *, collect_results: bool = True,
-                lat_hist=None):
+    def streams(self, n: int, *, collect_results: bool = True):
         """Multi-stream plan driver over the server's PM prefix index
         (``kv.prefix``), mirroring admission telemetry — above all the
         ``stream_deferred_plans`` contention counter — into
@@ -371,7 +370,7 @@ class Server:
         from ..distributed import StreamDriver
         return StreamDriver(self.kv.prefix, n,
                             collect_results=collect_results,
-                            lat_hist=lat_hist, metrics=self.metrics)
+                            metrics=self.metrics)
 
     def submit(self, prompt: List[int], max_new: int = 16, *,
                sid: int = 0) -> int:

@@ -29,16 +29,15 @@ module moves off it:
   honest counters never race.
 
 Telemetry: both objects count into an attached ``obs.MetricsRegistry``
-(``pipeline_*`` / ``async_export*`` names) so ``Server.stats`` and the
-benchmarks see pipeline depth, stalls, and export backlog alongside
-the probe-traffic counters.
+(``pipeline_*`` / ``async_export*`` names) so ``Server.stats`` shows
+pipeline depth, stalls, and export backlog alongside the probe-traffic
+counters.
 """
 
 from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -144,13 +143,12 @@ class AsyncExporter:
 class PlanTicket:
     """Deferred result of one pipelined plan submission."""
 
-    __slots__ = ("plan", "result", "error", "exec_ns", "_event")
+    __slots__ = ("plan", "result", "error", "_event")
 
     def __init__(self, plan: Plan):
         self.plan = plan
         self.result: Optional[PlanResult] = None
         self.error: Optional[BaseException] = None
-        self.exec_ns: int = 0
         self._event = threading.Event()
 
     @property
@@ -302,7 +300,6 @@ class PlanPipeline:
             self.exporter.run_pending()
 
     def _exec_single(self, ticket: PlanTicket, collect: bool) -> None:
-        t0 = time.perf_counter_ns()
         try:
             ticket.result = self.index.execute(
                 ticket.plan, collect_results=collect,
@@ -311,11 +308,9 @@ class PlanPipeline:
         except BaseException as e:  # surfaced at wait()/drain()
             ticket.error = e
         finally:
-            ticket.exec_ns = time.perf_counter_ns() - t0
             ticket._event.set()
 
     def _exec_group(self, group: List[Tuple[PlanTicket, bool]]) -> None:
-        t0 = time.perf_counter_ns()
         try:
             arrs = [t.plan.arrays() for t, _ in group]
             merged = Plan.from_arrays(
@@ -343,11 +338,7 @@ class PlanPipeline:
             for ticket, _ in group:
                 ticket.error = e
         finally:
-            dt = time.perf_counter_ns() - t0
-            # batch-amortized wall attribution, proportional to op count
-            total = sum(len(t.plan) for t, _ in group) or 1
             for ticket, _ in group:
-                ticket.exec_ns = dt * len(ticket.plan) // total
                 ticket._event.set()
 
     def _run(self) -> None:

@@ -10,7 +10,6 @@ import pytest
 from repro.kernels.flash_attention import attention_ref, flash_attention, mha
 from repro.kernels.rwkv6_scan import wkv6, wkv6_heads, wkv6_ref
 from repro.kernels.mamba_scan import ssd, ssd_heads, ssd_ref
-from repro.kernels.clht_probe import batched_lookup, clht_probe, probe_ref
 from repro.kernels.paged_attention import paged_attention, paged_attention_ref
 
 RNG = np.random.default_rng(0)
@@ -118,21 +117,6 @@ def test_ssd_heads_wrapper():
 # ----------------------------------------------------------------------
 # clht probe
 # ----------------------------------------------------------------------
-@pytest.mark.parametrize("Q,qb", [(512, 256), (256, 128), (1024, 256)])
-def test_clht_probe(Q, qb):
-    W = 128
-    bk = jnp.asarray(RNG.integers(1, 1000, size=(Q, W)), jnp.int32)
-    hit_col = RNG.integers(0, W, size=Q)
-    take = RNG.random(Q) < 0.5
-    q = jnp.where(jnp.asarray(take),
-                  bk[jnp.arange(Q), hit_col], jnp.int32(123456789))
-    bv = jnp.asarray(RNG.integers(1, 1 << 30, size=(Q, W)), jnp.int32)
-    f, v = clht_probe(q, bk, bv, query_block=qb)
-    fr, vr = probe_ref(q, bk, bv)
-    assert bool(jnp.all(f == fr))
-    assert bool(jnp.all(jnp.where(fr, v == vr, True)))
-
-
 def test_clht_probe_end_to_end_with_index():
     """Control-plane P-CLHT → exported arrays → Pallas batched lookup,
     bit-identical to the scalar reader (full 64-bit keys and values)."""

@@ -1,7 +1,7 @@
 """Tests for the repro.obs telemetry subsystem: span nesting, the
 histogram-vs-numpy percentile oracle, registry merge semantics,
 disabled-mode no-op behavior, trace-JSON schema round-trip, and the
-end-to-end guarantees the benchmarks rely on (exact per-wave counter
+end-to-end guarantees the chip benchmark reads (exact per-wave counter
 attribution, serving stats view, recovery span)."""
 
 import json
@@ -136,13 +136,6 @@ def test_histogram_merge_equals_union():
         assert ha.percentile(q) == hu.percentile(q)
 
 
-def test_histogram_record_batch():
-    h = Histogram()
-    h.record_batch(10_000, 10)  # 10 ops at mean 1000
-    assert h.n == 10 and h.total == 10_000
-    assert h.percentile(50) == bucket_upper(bucket_index(1000))
-
-
 def test_histogram_empty():
     h = Histogram()
     assert h.percentile(50) == 0 and h.mean == 0.0
@@ -245,6 +238,68 @@ def test_plan_wave_attribution_exact():
     for field in ("clwb", "fence", "stores", "loads"):
         total = sum(w.attrs[field] for w in waves)
         assert total == getattr(d, field), field
+
+
+def _validate_with_cli(obj, path):
+    """Write the trace and validate it the way the command line does
+    (``python -m repro.obs.trace <path>``)."""
+    from repro.obs import trace
+    path.write_text(json.dumps(obj))
+    assert trace.main([str(path)]) == 0
+
+
+def test_traced_ycsb_run_writes_a_valid_trace(tmp_path):
+    """A traced YCSB-A run on P-CLHT: the per-wave clwb/fence add up to
+    the run's PMem counter delta, and its trace file validates."""
+    pm = PMem()
+    idx = PCLHT(pm, n_buckets=512)
+    wl = generate("A", 600, 600, seed=7)
+    run_workload(idx, wl, phase="load", batch_lookups=True)
+    obs.enable()
+    c0 = pm.counters.snapshot()
+    run_workload(idx, wl, phase="run", batch_lookups=True)
+    d = pm.counters.delta(c0)
+    obs.disable()
+    waves = obs.spans("plan.wave")
+    assert (sum(w.attrs["clwb"] for w in waves),
+            sum(w.attrs["fence"] for w in waves)) == (d.clwb, d.fence)
+    _validate_with_cli(chrome_trace(obs.RECORDER), tmp_path / "trace.json")
+
+
+def test_traced_sharded_streams_write_a_valid_trace(tmp_path):
+    """YCSB-A over four P-CLHT shards driven by two client streams,
+    then a mesh read that re-exports under the tracer: the mesh agrees
+    with the per-shard path, the ``shard.plan`` + ``shard.export``
+    counter attributes add up to the ``ShardedPMem`` delta, and the
+    trace file validates."""
+    from repro.distributed import ShardedIndex, StreamDriver
+    wl = generate("A", 600, 600, seed=7)
+    idx = ShardedIndex(lambda p: PCLHT(p, n_buckets=512), 4)
+    idx.execute(Plan.from_ops(wl.load_ops), collect_results=False)
+    gets = Plan.from_ops([("lookup", k, 0) for _, k, _ in wl.load_ops[:300]])
+    r_ps = idx.execute(gets, mesh=False)
+    r_mesh = idx.execute(gets, mesh=True)
+    assert r_mesh.mesh and not r_ps.mesh
+    assert (r_mesh.found, r_mesh.results) == (r_ps.found, r_ps.results)
+    obs.enable()
+    c0 = idx.pmem.counters.snapshot()
+    drv = StreamDriver(idx, 2)
+    for i in range(0, len(wl.run_ops), 100):
+        drv.streams[(i // 100) % 2].submit(
+            Plan.from_ops(wl.run_ops[i:i + 100]))
+    drv.run()
+    # the run's inserts moved shard epochs: this mesh read re-exports
+    # under the tracer, so shard.export spans join the books
+    assert idx.execute(gets, mesh=True).found == r_mesh.found
+    d = idx.pmem.counters.delta(c0)
+    obs.disable()
+    assert drv.stats["ticks"] > 0 and drv.stats["admitted_plans"] > 0
+    spans = obs.spans("shard.plan") + obs.spans("shard.export")
+    assert obs.spans("shard.export")
+    for field in ("stores", "loads", "clwb", "fence", "lines_touched"):
+        assert sum(sp.attrs.get(field, 0) for sp in spans) == \
+            getattr(d, field), field
+    _validate_with_cli(chrome_trace(obs.RECORDER), tmp_path / "trace.json")
 
 
 def test_single_op_plan_emits_wave_span():

@@ -268,8 +268,8 @@ def test_mid_wave_crash_prefix_consistent(name, factory):
 
 
 def test_plan_result_telemetry():
-    """Wave counts and widths surface through PlanResult (the
-    BENCH_ycsb.json scheduler-quality rows)."""
+    """Wave counts and widths surface through PlanResult (what
+    ``run_workload`` tallies as the scheduler's waves and wave ops)."""
     idx = PCLHT(PMem(), n_buckets=64)
     plan = Plan()
     for k in range(100):
