@@ -19,10 +19,13 @@ Execution placement:
   ``placement`` ("devices" or "one_device").
 
 The jitted program is ``mesh_probe`` in either placement, so a device
-trace names it ``jit_mesh_probe``.  Traced, ``mesh_lookup`` opens a
-``kernel.launch`` span (query padding, splits, upload, the call) and a
-``kernel.fetch`` span (first to last download of the outputs), as the
-read kernels of ``kernels/`` do.
+trace names it ``jit_mesh_probe``.  As the read kernels of ``kernels/``
+do, a call makes one upload (every shard's query halves stacked
+``[S, 2, q_pad]``, onto one device; the jit reshards it) and one
+download (the outputs stacked ``[S, 3, q_pad]``, gathered from every
+shard's device).  Traced, ``mesh_lookup`` opens a ``kernel.launch``
+span (query padding, splits, the upload, the call) and a
+``kernel.fetch`` span (the download, so the wait for the program too).
 
 64-bit keys are handled the same way kernels/scan handles them, with
 its ``lower_bound``: split into int32 halves with the low half
@@ -124,15 +127,20 @@ def _stack(runs: Sequence[Optional[Tuple[np.ndarray, np.ndarray]]]
         steps=max(1, n_pad.bit_length()), n_shards=S)
 
 
-def _probe_one_shard(khi, klo, vhi, vlo, n, qhi, qlo, *, steps: int):
+def _probe_one_shard(khi, klo, vhi, vlo, n, q, *, steps: int):
     """Lower bound + equality over ONE shard's run: the per-device
-    program ``shard_map``/``vmap`` replicate across the shard axis."""
+    program ``shard_map``/``vmap`` replicate across the shard axis.
+    ``q`` is the shard's [2, q_pad] rows (qhi, qlo biased); returns
+    [3, q_pad] int32 rows (found, value hi, value lo)."""
     import jax.numpy as jnp
+    from ..kernels.probe import pack_outputs
     from ..kernels.scan import lower_bound
+    qhi, qlo = q
     lo = lower_bound(khi, klo, n, qhi, qlo, steps=steps)
     pos = jnp.clip(lo, 0, khi.shape[0] - 1)
     found = (lo < n) & (khi[pos] == qhi) & (klo[pos] == qlo)
-    return found, jnp.where(found, vhi[pos], 0), jnp.where(found, vlo[pos], 0)
+    return pack_outputs(found, jnp.where(found, vhi[pos], 0),
+                        jnp.where(found, vlo[pos], 0))
 
 
 @functools.lru_cache(maxsize=8)
@@ -142,18 +150,21 @@ def shard_mesh(n_shards: int):
     return jax.make_mesh((n_shards,), ("shard",))
 
 
-def mesh_probe(khi, klo, vhi, vlo, n, qhi, qlo, *, steps: int, mesh=None):
+def mesh_probe(khi, klo, vhi, vlo, n, q, *, steps: int, mesh=None):
     """The all-shard probe over ``[S, ...]`` stacked inputs: the
     vmapped per-shard search, under ``shard_map`` on ``mesh`` (one
-    device per shard) when one is given."""
+    device per shard) when one is given.  ``q`` is the one packed
+    query upload, [S, 2, q_pad] int32 (qhi, qlo biased); the result is
+    the one packed download, [S, 3, q_pad] int32 (found as 0/1, value
+    hi, value lo), split by shard over the mesh."""
     import jax
     fn = jax.vmap(functools.partial(_probe_one_shard, steps=steps))
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
         spec = P("shard")
-        fn = jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 7,
-                           out_specs=(spec, spec, spec))
-    return fn(khi, klo, vhi, vlo, n, qhi, qlo)
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 6,
+                           out_specs=spec)
+    return fn(khi, klo, vhi, vlo, n, q)
 
 
 @functools.lru_cache(maxsize=32)
@@ -180,12 +191,14 @@ def mesh_lookup(stacked: StackedRuns,
     """Probe all shards in one dispatch.  ``queries[s]`` is shard s's
     (possibly empty) int64 query vector; returns per-shard
     (found [Qs] bool, values [Qs] int64), bit-identical to probing each
-    shard's sorted run with ``kernels.scan.sorted_lookup``.  ``stats``
-    (one probe_stats dict per shard) takes each shard's query row of
-    the upload; the caller's open ``span`` takes the pads (``q_pad``,
-    ``n_pad``) and the longest live run (``run_max``)."""
-    from ..kernels.probe import combine64, split64
-    import jax.numpy as jnp
+    shard's sorted run with ``kernels.scan.sorted_lookup``.  One upload
+    of the packed [S, 2, q_pad] queries and one download of the packed
+    [S, 3, q_pad] outputs per call.  ``stats`` (one probe_stats dict
+    per shard) takes each shard's query row of the upload; the
+    caller's open ``span`` takes the pads (``q_pad``, ``n_pad``) and
+    the longest live run (``run_max``)."""
+    from ..kernels.probe import (combine64, fetch_packed, split64,
+                                 upload_packed)
     S = stacked.n_shards
     assert len(queries) == S
     q_len = [int(np.asarray(q).shape[0]) for q in queries]
@@ -193,27 +206,24 @@ def mesh_lookup(stacked: StackedRuns,
         q_pad = 8
         while q_pad < max(q_len + [1]):
             q_pad <<= 1
-        qhi = np.zeros((S, q_pad), np.int32)
-        qlo = np.zeros((S, q_pad), np.int32)
+        host = np.zeros((S, 2, q_pad), np.int32)  # rows: qhi, qlo
         for s, q in enumerate(queries):
             if q_len[s]:
                 lo, hi = split64(np.asarray(q, np.int64))
-                qhi[s, :q_len[s]] = hi
-                qlo[s, :q_len[s]] = lo
+                host[s, 0, :q_len[s]] = hi
+                host[s, 1, :q_len[s]] = lo
+        host[:, 1] ^= _BIAS
         fn = compiled_probe(stacked.steps, shard_mesh(S)
                             if placement(S) == "devices" else None)
-        qhi, qlo = jnp.asarray(qhi), jnp.asarray(qlo ^ _BIAS)
-        nbytes = int(qhi.nbytes) + int(qlo.nbytes)
-        _book_rows(stats, nbytes // S)
-        if lsp:
-            lsp.set(bytes=nbytes)
+        packed = upload_packed(host, lsp)
+        _book_rows(stats, int(packed.nbytes) // S)
         out = fn(stacked.khi, stacked.klo, stacked.vhi, stacked.vlo,
-                 stacked.n, qhi, qlo)
-    with _OBS.span("kernel.fetch", arrays=len(out)):
-        found, vhi, vlo = (np.asarray(o) for o in out)
+                 stacked.n, packed)
+    out = fetch_packed(out)
     if span:
         span.set(q_pad=q_pad, n_pad=stacked.n_pad, run_max=stacked.run_max)
-    vals = combine64(vlo, vhi)
+    found = out[:, 0].astype(bool)
+    vals = combine64(out[:, 2], out[:, 1])
     return [(found[s, :q_len[s]], vals[s, :q_len[s]]) for s in range(S)]
 
 

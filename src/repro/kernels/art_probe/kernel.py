@@ -26,22 +26,28 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..probe.ops import pack_outputs
+
 KEY_BYTES = 8
 
 
 @jax.jit
-def art_descend(qbytes, qlo, qhi, qfp, children, level, is_leaf, lfp,
-                lklo, lkhi, lvlo, lvhi):
-    """qbytes: [Q, U] int32 big-endian key units (U=8 bytes for P-ART,
-    U=16 nibbles for P-HOT); qlo/qhi: [Q] int32 key halves; qfp: [Q]
-    int32 partial-key fingerprints (fingerprint.fp_partial); children:
-    [N, 2**unit_bits] int32 (-1 none); level/is_leaf/lfp/leaf key-value
-    halves: [N] int32 (lfp is the export's ``leaf_fp`` lane, 0 for
-    non-leaf rows).  Returns (found [Q] bool, value_lo, value_hi [Q]
-    int32, n_leaf_checks, n_fp_match, n_fp_false [Q] int32) — found and
-    values are unchanged by the fingerprint pre-pass; the counts feed
-    the probe-traffic model."""
-    Q, U = qbytes.shape  # U key units per key (8 bytes or 16 nibbles)
+def art_descend(queries, children, level, is_leaf, lfp, lklo, lkhi, lvlo,
+                lvhi):
+    """queries: the one packed upload, [U + 3, Q] int32 rows: U
+    big-endian key units (U=8 bytes for P-ART, U=16 nibbles for P-HOT),
+    then the key halves qlo and qhi and the partial-key fingerprint
+    (fingerprint.fp_partial); children: [N, 2**unit_bits] int32 (-1
+    none); level/is_leaf/lfp/leaf key-value halves: [N] int32 (lfp is
+    the export's ``leaf_fp`` lane, 0 for non-leaf rows).  Returns the
+    one packed download, [6, Q] int32 rows: found (0/1), value_lo,
+    value_hi, n_leaf_checks, n_fp_match, n_fp_false — found and values
+    are unchanged by the fingerprint pre-pass; the counts feed the
+    probe-traffic model."""
+    U = queries.shape[0] - 3  # key units per key (8 bytes or 16 nibbles)
+    Q = queries.shape[1]
+    qbytes = queries[:U].T
+    qlo, qhi, qfp = queries[U:]
     node = jnp.zeros((Q,), jnp.int32)  # node 0 is the root
     active = jnp.ones((Q,), jnp.bool_)
     found = jnp.zeros((Q,), jnp.bool_)
@@ -74,4 +80,4 @@ def art_descend(qbytes, qlo, qhi, qfp, children, level, is_leaf, lfp,
         child = children[node, byte]
         active = active & (child >= 0)
         node = jnp.where(active, child, node)
-    return found, olo, ohi, nenc, nfp, nfalse
+    return pack_outputs(found, olo, ohi, nenc, nfp, nfalse)

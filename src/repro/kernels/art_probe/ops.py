@@ -20,7 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...obs import RECORDER as _OBS
-from ..probe import book_upload, combine64, pad_queries, split64
+from ..probe import (book_upload, combine64, fetch_packed, pad_queries,
+                     split64, upload_packed)
 from ..probe.fingerprint import account, fp_partial
 from .kernel import art_descend
 from .ref import leaf_fp_lane
@@ -76,13 +77,11 @@ def _descend(queries: np.ndarray, pages: tuple, *,
                 q = np.pad(q, (0, pad))  # padded lanes miss at the leaf
             qlo, qhi = split64(q)
             qfp = fp_partial(q).astype(np.int32)
-            args = [jnp.asarray(a)
-                    for a in (key_units(q, unit_bits), qlo, qhi, qfp)]
-            book_upload(stats, lsp, args)
-            out = art_descend(*args, *node_pages)
-        with _OBS.span("kernel.fetch", arrays=len(out)):
-            found, olo, ohi, nenc, nfp, nfalse = (np.asarray(o)[:Q]
-                                                  for o in out)
+            host = np.concatenate([key_units(q, unit_bits).T,
+                                   np.stack([qlo, qhi, qfp])])
+            out = art_descend(upload_packed(host, lsp, stats), *node_pages)
+        found, olo, ohi, nenc, nfp, nfalse = fetch_packed(out)[:, :Q]
+        found = found.astype(bool)
         values = combine64(olo, ohi)
         # lanes = leaves actually reached (the radix descent has no
         # fixed window; internal hops are index words, not key lanes)

@@ -20,8 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...obs import RECORDER as _OBS
-from ..probe import (book_upload, combine64, pad_queries, probe64_lookup,
-                     split64)
+from ..probe import (book_upload, combine64, fetch_packed, pack_outputs,
+                     pad_queries, probe64_lookup, split64, upload_packed)
 from ..probe.fingerprint import account, fp64
 from ..probe.kernel import probe64, probe64_fp
 
@@ -55,13 +55,18 @@ def batched_lookup(queries: np.ndarray, keys: np.ndarray, vals: np.ndarray,
 
 
 @functools.partial(jax.jit, static_argnames=("depth", "use_fp"))
-def _gather_probe(bucket, qlo, qhi, qfp, klo, khi, vlo, vhi, fps, nxt, *,
+def _gather_probe(queries, klo, khi, vlo, vhi, fps, nxt, *,
                   depth: int, use_fp: bool):
     """Fused probe: the XLA gather chases each query's overflow chain
     (``depth`` = the snapshot's longest chain) and feeds the windows
     straight to the probe64 kernel — nothing materializes on the host.
     With ``use_fp`` the fingerprint lane is windowed alongside and the
-    fingerprint-compare pre-pass kernel runs instead."""
+    fingerprint-compare pre-pass kernel runs instead.  ``queries`` is
+    the one packed upload, [4, Q] int32 rows (bucket, qlo, qhi, qfp);
+    the result is the one packed download, [5, Q] int32 rows (found,
+    value_lo, value_hi, n_fp_match, n_fp_false), or its first 3 rows
+    without ``use_fp``."""
+    bucket, qlo, qhi, qfp = queries
     with jax.named_scope("chain_walk"):
         rows = []
         cur = bucket
@@ -76,8 +81,8 @@ def _gather_probe(bucket, qlo, qhi, qfp, klo, khi, vlo, vhi, fps, nxt, *,
             windows.append(jnp.concatenate(parts, axis=1))
     with jax.named_scope("probe"):
         if use_fp:
-            return probe64_fp(qlo, qhi, qfp, *windows)
-        return probe64(qlo, qhi, *windows)
+            return pack_outputs(*probe64_fp(qlo, qhi, qfp, *windows))
+        return pack_outputs(*probe64(qlo, qhi, *windows))
 
 
 def _chain_depth(nxt: np.ndarray) -> int:
@@ -200,7 +205,10 @@ def snapshot_lookup(snap, queries: np.ndarray, *, fingerprints: bool = True,
     device, and measure the longest overflow chain.  Per batch: 64-bit
     bucket hash on the host (splitmix64 needs real uint64), then one
     fused gather+probe call — fingerprint pre-pass first when
-    ``fingerprints`` is on, with filter counts folded into ``stats``."""
+    ``fingerprints`` is on, with filter counts folded into ``stats``.
+    A batch makes one upload (bucket, key halves and fingerprint
+    stacked [4, Q]) and one download (the outputs stacked [5, Q] or
+    [3, Q]); padded rows are sliced off before anything is counted."""
     prepared = snap.cache.get("clht_probe")
     if prepared is None:
         prepared = snap.cache["clht_probe"] = _prepare(snap, stats)
@@ -219,13 +227,12 @@ def snapshot_lookup(snap, queries: np.ndarray, *, fingerprints: bool = True,
             bucket = (mix64(q) % _U64(n)).astype(np.int32)
             qlo, qhi = split64(q)
             qfp = fp64(q).astype(np.int32)
-            args = [jnp.asarray(a) for a in (bucket, qlo, qhi, qfp)]
-            book_upload(stats, lsp, args)
-            out = _gather_probe(*args, *halves, fps_dev, nxt_dev,
+            packed = upload_packed(np.stack([bucket, qlo, qhi, qfp]), lsp,
+                                   stats)
+            out = _gather_probe(packed, *halves, fps_dev, nxt_dev,
                                 depth=depth, use_fp=fingerprints)
-        with _OBS.span("kernel.fetch", arrays=len(out)):
-            out = [np.asarray(o)[:Q] for o in out]
-        found = out[0]
+        out = fetch_packed(out)[:, :Q]
+        found = out[0].astype(bool)
         values = combine64(out[1], out[2])
         if fingerprints:
             cand = int(out[3].sum())

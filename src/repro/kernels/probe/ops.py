@@ -7,10 +7,18 @@ overflow chains, and pad query batches to the kernel's block multiple.
 All of it is plain numpy: the gathers are snapshot-array indexing (the
 XLA/VPU work is the wide compare in kernel.py), and 64-bit hashing
 cannot run inside default-precision jax anyway.
+
+The read kernels' transfer protocol is here too: every read-kernel call
+stacks its per-query arrays into one int32 array on the host and
+uploads it once (``upload_packed``), and its jitted program stacks its
+outputs into one int32 array (``pack_outputs``) that the host downloads
+once (``fetch_packed``).  Each transfer is a round trip to the device,
+so the count, not the bytes, sets a small batch's wait.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -53,7 +61,30 @@ def book_upload(stats: Optional[dict], sp, arrays: Sequence, *,
     if sp:
         if wait:
             jax.block_until_ready(list(arrays))
-        sp.set(bytes=nbytes)
+        sp.set(bytes=nbytes, arrays=len(arrays))
+
+
+def upload_packed(host: np.ndarray, sp, stats: Optional[dict] = None):
+    """A read-kernel call's one upload: the packed int32 query array
+    ``host`` goes to the device in a single transfer, booked as
+    ``book_upload`` books it (the open span's ``arrays`` reads 1)."""
+    dev = jnp.asarray(host)
+    book_upload(stats, sp, [dev])
+    return dev
+
+
+def pack_outputs(*outs):
+    """Inside a jitted read program: its outputs stacked into one int32
+    array (``found``/``valid`` as 0/1), the call's single download."""
+    return jnp.stack([o.astype(jnp.int32) for o in outs])
+
+
+def fetch_packed(out) -> np.ndarray:
+    """A read-kernel call's one download: ``out``, the program's packed
+    int32 output, in a ``kernel.fetch`` span (which holds the wait for
+    the program too)."""
+    with _OBS.span("kernel.fetch", arrays=1):
+        return np.asarray(out)
 
 
 def gather_chain_windows(start: np.ndarray, nxt: np.ndarray,
@@ -102,6 +133,21 @@ def pad_queries(n: int, block: int = QUERY_BLOCK) -> int:
     return p - n
 
 
+@functools.partial(jax.jit, static_argnames=("use_fp",))
+def _probe_packed(packed, *, use_fp: bool):
+    """probe64 (or probe64_fp) over one packed upload: [Q, C + N*W]
+    int32, the C query columns (qlo, qhi[, qfp]) and then the N windows
+    (klo, khi, vlo, vhi[, wfp]) side by side.  Returns the outputs
+    stacked, [3, Q] (or [5, Q])."""
+    n_cols = 3 if use_fp else 2
+    n_win = n_cols + 2
+    W = (packed.shape[1] - n_cols) // n_win
+    cols = [packed[:, i] for i in range(n_cols)]
+    wins = [packed[:, n_cols + i * W:n_cols + (i + 1) * W]
+            for i in range(n_win)]
+    return pack_outputs(*(probe64_fp if use_fp else probe64)(*cols, *wins))
+
+
 def probe64_windows(queries: np.ndarray, split_windows: Sequence[np.ndarray],
                     *, fp_window: Optional[np.ndarray] = None,
                     fingerprints: bool = True, stats: Optional[dict] = None
@@ -133,19 +179,19 @@ def probe64_windows(queries: np.ndarray, split_windows: Sequence[np.ndarray],
                 klo, khi, vlo, vhi = (np.pad(w, ((0, pad), (0, 0)))
                                       for w in (klo, khi, vlo, vhi))
             qlo, qhi = split64(queries)
+            cols = [qlo, qhi]
+            windows = [klo, khi, vlo, vhi]
             if use_fp:
                 if pad:
                     fp_window = np.pad(fp_window, ((0, pad), (0, 0)))
-                host = [qlo, qhi, fp64(queries).astype(np.int32), klo, khi,
-                        vlo, vhi, fp_window.astype(np.int32)]
-            else:
-                host = [qlo, qhi, klo, khi, vlo, vhi]
-            args = [jnp.asarray(a) for a in host]
-            book_upload(stats, lsp, args)
-            out = (probe64_fp if use_fp else probe64)(*args)
-        with _OBS.span("kernel.fetch", arrays=len(out)):
-            out = [np.asarray(o)[:Q] for o in out]
-        found = out[0]
+                cols.append(fp64(queries).astype(np.int32))
+                windows.append(fp_window.astype(np.int32))
+            host = np.concatenate([np.stack(cols, axis=1), *windows],
+                                  axis=1)
+            out = _probe_packed(upload_packed(host, lsp, stats),
+                                use_fp=use_fp)
+        out = fetch_packed(out)[:, :Q]
+        found = out[0].astype(bool)
         values = combine64(out[1], out[2])
         if use_fp:
             # counters over the real (un-padded) query rows only

@@ -35,6 +35,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..probe.ops import pack_outputs
+
 _BIAS = -(1 << 31)  # XOR bias realizing unsigned int32 order
 
 
@@ -62,22 +64,24 @@ def lower_bound(khi, klo, n, qhi, qlo, *, steps: int):
 
 
 @functools.partial(jax.jit, static_argnames=("steps", "max_count"))
-def scan_window(qlo, qhi, counts, klo, khi, vlo, vhi, n, *, steps: int,
+def scan_window(queries, klo, khi, vlo, vhi, n, *, steps: int,
                 max_count: int):
-    """qlo/qhi: [Q] int32 query-key halves (lo pre-biased); counts: [Q]
-    int32 requested window widths; klo/khi/vlo/vhi: [N] int32 halves of
-    the sorted run (klo pre-biased); n: int32 live-entry count; steps:
-    host-computed ceil(log2(n+1)).  Returns (valid [Q, C] bool, key_lo,
-    key_hi, val_lo, val_hi [Q, C] int32) — rows are prefix masks, keys
-    come back un-biased."""
+    """queries: the one packed upload, [3, Q] int32 rows: query-key
+    halves qlo (pre-biased) and qhi, and the requested window widths;
+    klo/khi/vlo/vhi: [N] int32 halves of the sorted run (klo
+    pre-biased); n: int32 live-entry count; steps: host-computed
+    ceil(log2(n+1)).  Returns the one packed download, [5, Q, C] int32:
+    valid (0/1), key_lo, key_hi, val_lo, val_hi — valid rows are prefix
+    masks, keys come back un-biased."""
+    qlo, qhi, counts = queries
     with jax.named_scope("search"):
         lo = lower_bound(khi, klo, n, qhi, qlo, steps=steps)
     off = jax.lax.broadcasted_iota(jnp.int32, (qlo.shape[0], max_count), 1)
     pos = lo[:, None] + off
     ok = (off < counts[:, None]) & (pos < n)
     safe = jnp.clip(pos, 0, klo.shape[0] - 1)
-    return (ok,
-            jnp.where(ok, klo[safe] ^ _BIAS, 0),  # un-bias keys
-            jnp.where(ok, khi[safe], 0),
-            jnp.where(ok, vlo[safe], 0),
-            jnp.where(ok, vhi[safe], 0))
+    return pack_outputs(ok,
+                        jnp.where(ok, klo[safe] ^ _BIAS, 0),  # un-bias
+                        jnp.where(ok, khi[safe], 0),
+                        jnp.where(ok, vlo[safe], 0),
+                        jnp.where(ok, vhi[safe], 0))

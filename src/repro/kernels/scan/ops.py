@@ -3,7 +3,8 @@
 Splits the 64-bit sorted run into int32 halves (low halves XOR-biased
 so signed lane compares realize unsigned 64-bit order), pads query
 batches to a small family of shapes, and re-assembles per-query result
-rows.
+rows.  A batch makes one upload (query halves and counts stacked
+[3, Q]) and one download (the outputs stacked [5, Q, C]).
 The prepared device form is memoized on the ``IndexSnapshot`` under the
 ``"scan"`` cache key, so steady-state batches pay gather + kernel only.
 """
@@ -16,7 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...obs import RECORDER as _OBS
-from ..probe import book_upload, combine64, split64
+from ..probe import (book_upload, combine64, fetch_packed, split64,
+                     upload_packed)
 from ..probe.fingerprint import account, fp64
 from .kernel import scan_window
 
@@ -85,13 +87,12 @@ def _run_kernel(queries: np.ndarray, counts: np.ndarray, prepared: tuple,
                 q = np.pad(q, (0, pad))
                 c = np.pad(c, (0, pad))
             qlo, qhi = split64(q)
-            args = [jnp.asarray(qlo ^ _BIAS), jnp.asarray(qhi),
-                    jnp.asarray(c)]
-            book_upload(stats, lsp, args)
-            out = scan_window(*args, klo, khi, vlo, vhi, n_dev,
+            packed = upload_packed(np.stack([qlo ^ _BIAS, qhi, c]), lsp,
+                                   stats)
+            out = scan_window(packed, klo, khi, vlo, vhi, n_dev,
                               steps=steps, max_count=C)
-        with _OBS.span("kernel.fetch", arrays=len(out)):
-            valid, oklo, okhi, ovlo, ovhi = (np.asarray(o)[:Q] for o in out)
+        valid, oklo, okhi, ovlo, ovhi = fetch_packed(out)[:, :Q]
+        valid = valid.astype(bool)
         okeys = combine64(oklo, okhi)
         ovals = combine64(ovlo, ovhi)
     return valid, okeys, ovals

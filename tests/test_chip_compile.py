@@ -73,13 +73,14 @@ def spec(shape, sharding, dtype=jnp.int32):
 @pytest.mark.parametrize("depth", [CLHT_DEPTH, CHAIN_CAP])
 @pytest.mark.parametrize("use_fp", [False, True])
 def test_clht_gather_probe_compiles(chip, depth, use_fp):
-    """The fused chain gather + probe64 / probe64_fp Pallas kernel."""
-    col = spec((Q,), chip)
+    """The fused chain gather + probe64 / probe64_fp Pallas kernel, fed
+    by one packed query upload and giving one packed output."""
     table = spec((CLHT_ROWS, 3), chip)
     compiled = clht_ops._gather_probe.lower(
-        col, col, col, col, table, table, table, table, table,
+        spec((4, Q), chip), table, table, table, table, table,
         spec((CLHT_ROWS,), chip), depth=depth, use_fp=use_fp).compile()
     assert "tpu_custom_call" in compiled.as_text()  # a kernel, compiled
+    assert compiled.out_info.shape == (5 if use_fp else 3, Q)
 
 
 def test_clht_delta_scatter_compiles(chip):
@@ -103,11 +104,11 @@ def test_clht_delta_scatter_compiles(chip):
 def test_sorted_run_search_compiles(chip, queries, window):
     """Lower bound + window gather over a 2^18-entry run (lookup and
     scan shapes)."""
-    col = spec((queries,), chip)
     run = spec((SORTED_RUN,), chip)
     compiled = scan_window.lower(
-        col, col, col, run, run, run, run, spec((), chip),
+        spec((3, queries), chip), run, run, run, run, spec((), chip),
         steps=SORTED_RUN.bit_length(), max_count=window).compile()
+    assert compiled.out_info.shape == (5, queries, window)
     # the whole run is an HBM argument of the XLA program
     assert compiled.memory_analysis().argument_size_in_bytes >= \
         4 * 4 * SORTED_RUN
@@ -115,11 +116,11 @@ def test_sorted_run_search_compiles(chip, queries, window):
 
 def test_radix_descent_compiles(chip):
     """The P-ART descent over a 2^18-key export's node pages."""
-    col = spec((Q,), chip)
     nodes = spec((ART_NODES,), chip)
     compiled = art_descend.lower(
-        spec((Q, 8), chip), col, col, col, spec((ART_NODES, 256), chip),
+        spec((8 + 3, Q), chip), spec((ART_NODES, 256), chip),
         *([nodes] * 7)).compile()
+    assert compiled.out_info.shape == (6, Q)
     # the child pages are an HBM argument of the XLA program
     assert compiled.memory_analysis().argument_size_in_bytes >= \
         4 * 256 * ART_NODES
@@ -132,8 +133,9 @@ def test_mesh_lookup_compiles_on_four_devices(topo, chip):
     rows = NamedSharding(mesh, P("shard"))
     run_len = SORTED_RUN // SHARDS
     run = spec((SHARDS, run_len), rows)
-    q = spec((SHARDS, 2 * Q // SHARDS), rows)
+    q = spec((SHARDS, 2, 2 * Q // SHARDS), rows)
     fn = compiled_probe(run_len.bit_length(), mesh)
     compiled = fn.lower(run, run, run, run, spec((SHARDS,), rows),
-                        q, q).compile()
-    assert len(compiled.output_shardings[0].device_set) == SHARDS
+                        q).compile()
+    assert compiled.out_info.shape == (SHARDS, 3, 2 * Q // SHARDS)
+    assert len(compiled.output_shardings.device_set) == SHARDS
